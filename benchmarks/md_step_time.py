@@ -7,7 +7,7 @@ distributed slab driver's whole-trajectory outer program on forced host
 devices. Writes ``BENCH_md.json`` so CI records the perf trajectory per PR:
 
   PYTHONPATH=src python benchmarks/md_step_time.py [--tiny] [--out BENCH_md.json]
-  PYTHONPATH=src python benchmarks/md_step_time.py --dist-slabs 2   # + slab driver
+  PYTHONPATH=src python benchmarks/md_step_time.py --dist-slabs 2   # + brick driver
 
 Engines are warmed first (compiles cached at module level), then reps are
 INTERLEAVED across engines (load spikes on shared runners tax everyone
@@ -17,9 +17,10 @@ dense: the scan engine pays one host rebuild + overflow sync + thermo
 fetch per segment, the outer engine folds all of it into its chunked scan
 — that per-segment saving is what ``speedup_outer_over_scan`` tracks.
 
-The distributed benchmark re-executes this script in a subprocess with
-``--dist-worker`` and XLA_FLAGS forcing host devices (the parent process
-cannot re-init jax with a different device count).
+The distributed legs run in this same process, on the devices it has: a
+chip belongs to one process, so a child could not open it. On CPU the
+script asks JAX for as many host devices as the largest topology needs
+before the backend starts.
 """
 
 import argparse
@@ -29,12 +30,16 @@ import statistics
 import subprocess
 import sys
 import time
+import traceback
 
 import jax
 
 from repro.core import dp_model
 from repro.core.types import DPConfig
+from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.md import api, driver, lattice
+from repro.md.topology import Topology
 
 
 def copper_cfg(tiny: bool) -> DPConfig:
@@ -101,27 +106,28 @@ def bench_single_process(args, steps: int, reps: int):
     return results, len(pos)
 
 
-def bench_distributed_worker(args, steps: int, reps: int) -> int:
-    """Runs INSIDE the forced-device subprocess: time the brick driver's
-    whole-trajectory outer program (migration + rebuild in the scan) on
-    the requested ``--dist-topology`` shape (``--dist-slabs k`` = (k,))."""
-    import time
-
+def _time_distributed(topo: Topology, potential_name: str,
+                      ensemble_name: str, rebuild_every: int, steps: int,
+                      reps: int) -> dict:
+    """Time the brick driver's whole-trajectory outer program (migration +
+    rebuild in the scan) on ``topo``, one brick per device."""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.md import api, domain, integrator, stepper
-    from repro.md.topology import Topology
+    from repro.md import domain, integrator, stepper
 
-    topo = Topology.parse(args.dist_topology or args.dist_slabs)
     n_slabs = topo.n_ranks
+    if len(jax.devices()) < n_slabs:
+        raise RuntimeError(
+            f"topology {topo.label()} needs {n_slabs} devices, this process "
+            f"has {len(jax.devices())}")
     # always the full config: the tiny sel=(32,) cannot hold the 4.5 A
     # copper neighborhood (~42 neighbors) and DomainSpec escalation is a
     # host replay — keep the timed loop overflow-free by construction
     cfg = copper_cfg(False)
-    ensemble, barostat = api.resolve_ensemble(args.ensemble)
-    if args.potential == "lj":
+    ensemble, barostat = api.resolve_ensemble(ensemble_name)
+    if potential_name == "lj":
         potential = api.LJPotential(sel=cfg.sel, rcut_lj=cfg.rcut)
         params = {}
     else:
@@ -134,7 +140,8 @@ def bench_distributed_worker(args, steps: int, reps: int) -> int:
     dims = [3 * topo.shape[a] if a < topo.ndim else 3 for a in range(3)]
     pos, typ, box = lattice.fcc_copper(*dims)
     n = len(pos)
-    mesh = jax.make_mesh((n_slabs, 1), ("data", "model"))
+    mesh = mesh_lib.make_mesh((n_slabs, 1), ("data", "model"),
+                              devices=jax.devices()[:n_slabs])
     cap = int(n / n_slabs * 1.5) + 8
     # skin 0.5: sel=(48,) holds the 4.5 A copper neighborhood with margin;
     # a 1.0 skin overflows it at 330 K. Later halo sweeps pack earlier
@@ -158,7 +165,7 @@ def bench_distributed_worker(args, steps: int, reps: int) -> int:
         donate=False, potential=potential, ensemble=ensemble,
         barostat=barostat)
     ens0 = program.init_ensemble_state()
-    sched = stepper.chunk_schedule(steps, args.rebuild_every, 8)
+    sched = stepper.chunk_schedule(steps, rebuild_every, 8)
 
     def one_run():
         state = state0
@@ -175,41 +182,30 @@ def bench_distributed_worker(args, steps: int, reps: int) -> int:
 
     one_run()                                                        # warm
     times = [one_run() for _ in range(reps)]
-    print(json.dumps({
+    return {
         "slabs": n_slabs, "topology": topo.label(), "n_atoms": n,
-        "atoms_per_rank": n // n_slabs, "devices": len(jax.devices()),
+        "atoms_per_rank": n // n_slabs, "devices": n_slabs,
         "engine": "outer_distributed",
-        "potential": args.potential, "ensemble": args.ensemble,
+        "potential": potential_name, "ensemble": ensemble_name,
         "us_per_step_atom_median": statistics.median(times),
         "us_per_step_atom_min": min(times),
         "us_per_step_atom_all": times,
-    }))
-    return 0
+    }
 
 
 def bench_distributed(args, steps: int, reps: int, topology=None,
                       potential=None, ensemble=None):
-    """Spawn the forced-device worker subprocess and parse its JSON line."""
-    from repro.md.topology import Topology
+    """One distributed row, timed in this process; a failure becomes a
+    ``{"status": "failed"}`` row (and a nonzero exit from :func:`main`)."""
     topo = Topology.parse(topology or args.dist_topology or args.dist_slabs)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count="
-                        f"{topo.n_ranks}").strip()
-    cmd = [sys.executable, os.path.abspath(__file__), "--dist-worker",
-           "--dist-topology", topo.label(),
-           "--potential", potential or args.potential,
-           "--ensemble", ensemble or args.ensemble,
-           "--rebuild-every", str(args.rebuild_every),
-           "--steps", str(steps), "--reps", str(reps)]
-    # (no --tiny forwarding: the worker always runs the full config — the
-    # tiny sel cannot hold the copper neighborhood, see the worker)
-    r = subprocess.run(cmd, capture_output=True, text=True, timeout=1200,
-                       env=env)
-    if r.returncode != 0:
-        print(f"  distributed bench FAILED:\n{r.stdout}\n{r.stderr}")
-        return {"status": "failed", "error": r.stderr[-500:]}
-    row = json.loads(r.stdout.strip().splitlines()[-1])
+    try:
+        row = _time_distributed(topo, potential or args.potential,
+                                ensemble or args.ensemble,
+                                args.rebuild_every, steps, reps)
+    except Exception as e:
+        traceback.print_exc()
+        print(f"  distributed bench FAILED: {type(e).__name__}: {e}")
+        return {"status": "failed", "error": f"{type(e).__name__}: {e}"}
     print(f"  engine=outer_distributed (topology {row['topology']}, "
           f"{row['n_atoms']} atoms, {row['atoms_per_rank']}/rank) median "
           f"{row['us_per_step_atom_median']:8.2f} us/step/atom "
@@ -354,22 +350,26 @@ def main(argv=None) -> int:
                          "1-D spelling of --dist-topology k")
     ap.add_argument("--dist-topology", default=None,
                     help="benchmark the distributed driver on this brick "
-                         "topology (e.g. 2x2x2); forces prod(shape) host "
-                         "devices in a subprocess")
+                         "topology (e.g. 2x2x2), one brick per device; on "
+                         "CPU this process gets prod(shape) host devices")
     ap.add_argument("--weak-scaling", action="store_true",
                     help="LJ weak-scaling sweep: constant atoms/rank over "
                          "topologies 2 -> 2x2 -> 2x2x2 (+ one NPT row), "
                          "appended to the BENCH trajectory keyed by "
                          "topology shape")
-    ap.add_argument("--dist-worker", action="store_true",
-                    help=argparse.SUPPRESS)
     ap.add_argument("--out", default="BENCH_md.json")
     args = ap.parse_args(argv)
 
     steps = args.steps or 99
     reps = args.reps or (3 if args.tiny else 5)
-    if args.dist_worker:
-        return bench_distributed_worker(args, steps, reps)
+    ranks = [Topology.parse(t).n_ranks for t in
+             ([args.dist_topology or args.dist_slabs]
+              if args.dist_slabs or args.dist_topology else [])
+             + (list(WEAK_SCALING_TOPOLOGIES) if args.weak_scaling else [])]
+    if ranks and jax.config.jax_num_cpu_devices < max(ranks):
+        # must precede the first JAX op; only the CPU backend reads it
+        jax.config.update("jax_num_cpu_devices", max(ranks))
+    enable_compile_cache()
 
     results, n_atoms = bench_single_process(args, steps, reps)
 

@@ -1,6 +1,9 @@
 """Benchmark driver: one module per paper table/figure. Prints CSV rows.
 
   PYTHONPATH=src python -m benchmarks.run [--only fig2,...]
+
+Every benchmark runs even when an earlier one fails; the exit status is 1
+if any of them failed.
 """
 
 from __future__ import annotations
@@ -50,12 +53,13 @@ def _print_rows(rows):
     sys.stdout.flush()
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names " + str(BENCHES))
     args = ap.parse_args(argv)
     names = args.only.split(",") if args.only else list(BENCHES)
+    failed = []
     for name in names:
         t0 = time.time()
         print(f"# ---- {name} ----", flush=True)
@@ -68,7 +72,11 @@ def main(argv=None) -> None:
             import traceback
             traceback.print_exc()
             print(f"# {name}: FAILED {type(e).__name__}: {e}", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"# FAILED: {','.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -50,16 +50,21 @@ def tabulate_model(params: Dict[str, Any], cfg: DPConfig, kind: str = "quintic",
     tables = {}
     for idx, net in params["embed"].items():
         g = embedding.embedding_scalar_fn(net)
-        if kind == "quintic":
-            tables[idx] = tabulation.build_quintic_table(
-                g, cfg.table_lower, cfg.table_upper, step or cfg.table_step
-            )
-        elif kind == "cheb":
-            tables[idx] = tabulation.build_cheb_table(
-                g, cfg.table_lower, cfg.table_upper, order or cfg.cheb_order
-            )
-        else:
-            raise ValueError(f"unknown table kind {kind}")
+        # Built once, so always at f32 accuracy: at the TPU's default (one
+        # bf16 pass per matmul) the K=32 Chebyshev coefficients carry
+        # rounding noise that differentiation amplifies, 5.7e-2 rms-relative
+        # force error at copper width.
+        with jax.default_matmul_precision("highest"):
+            if kind == "quintic":
+                tables[idx] = tabulation.build_quintic_table(
+                    g, cfg.table_lower, cfg.table_upper, step or cfg.table_step
+                )
+            elif kind == "cheb":
+                tables[idx] = tabulation.build_cheb_table(
+                    g, cfg.table_lower, cfg.table_upper, order or cfg.cheb_order
+                )
+            else:
+                raise ValueError(f"unknown table kind {kind}")
     out = dict(params)
     out["table"] = {"nets": tables}   # kind is carried by cfg.impl / impl arg
     return out
@@ -96,6 +101,7 @@ def _t_matrix_onetype(params, cfg: DPConfig, impl: str, center_type: int,
             t_parts.append(dp_fused_ops.fused_env_tab_contract(
                 env_sec, s_sec, table["coeffs"],
                 cfg.table_lower, cfg.table_upper,
+                interpret=cfg.kernel_interpret,
             ))
         else:
             g_sec = _g_section(params, cfg, impl, idx, s_sec)   # (..., sel_t, M)

@@ -50,6 +50,9 @@ class DPConfig:
 
     # --- numerics ---
     dtype: str = "float32"       # f32 default on TPU; f64 oracle path in tests
+    # run the Pallas kernel of "cheb_pallas" in interpret mode (CPU tests);
+    # the default compiles it for the TPU
+    kernel_interpret: bool = False
 
     @property
     def nsel(self) -> int:

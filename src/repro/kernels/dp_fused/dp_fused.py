@@ -26,9 +26,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.6 names the TPU compiler-params class TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+# Scoped-VMEM budget for both kernels. At copper width (K=32, M=128, one
+# (8, 128) tile) the compiler asks for ~16.1 MiB forward and ~17.1 MiB
+# backward, just past its 16 MiB default; a v5e TensorCore has 128 MiB.
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
 def _cheb_basis_pair(u: jax.Array, order: int, with_deriv: bool):
@@ -168,8 +169,9 @@ def fused_fwd(
             out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((a_pad, 4, m), s.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(tile_counts, s, env, coeffs)
@@ -211,8 +213,9 @@ def fused_bwd(
             jax.ShapeDtypeStruct((a_pad, n_pad), s.dtype),
             jax.ShapeDtypeStruct((a_pad, n_pad, 4), env.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(tile_counts, s, env, coeffs, dt)

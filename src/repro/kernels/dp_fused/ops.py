@@ -6,8 +6,9 @@ Notes:
     runs impl="mlp". Forces = dE/dpositions DO flow through s and env via
     the custom VJP (the paper evaluates forces in backward propagation
     through the tabulated model the same way).
-  * On non-TPU backends the kernel runs in interpret mode (correctness
-    validation); production dry-runs use the XLA path (ref.py) instead.
+  * ``interpret`` is always the caller's choice: the model path compiles
+    the kernel for the TPU (``DPConfig.kernel_interpret`` is False), and
+    CPU tests ask for interpret mode themselves.
 """
 
 from __future__ import annotations
@@ -20,11 +21,8 @@ import jax.numpy as jnp
 from repro.kernels.dp_fused import dp_fused
 
 DEFAULT_BLOCK_A = 8
+# (8, 128) is the smallest tile the TPU lowering accepts for the s block.
 DEFAULT_BLOCK_N = 128
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -92,15 +90,14 @@ def fused_env_tab_contract(
     *,
     block_a: int = DEFAULT_BLOCK_A,
     block_n: int = DEFAULT_BLOCK_N,
-    interpret: bool | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """T = R~^T G, G tabulated on the fly (never materialized in HBM).
 
     env: (..., N, 4); s: (..., N); coeffs: (K, M). Returns (..., 4, M).
-    Leading batch dims are flattened into the atom axis.
+    Leading batch dims are flattened into the atom axis. ``interpret=True``
+    runs the kernel through the Pallas interpreter (any backend).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     batch_shape = s.shape[:-1]
     n = s.shape[-1]
     env2 = env.reshape(-1, n, 4)

@@ -41,6 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis import roofline as rl
 from repro.core import dp_model
 from repro.core.types import COPPER_DP, WATER_DP, DPConfig
+from repro.kernels.dp_fused import ops as fused_ops
 from repro.launch import mesh as mesh_mod
 from repro.md import api, domain, stepper
 from repro.md.topology import Topology
@@ -150,7 +151,10 @@ def lower_md_cell(cell: MDCell, impl: str, mesh, multi_pod: bool,
         name += f"/outer{outer_segments}"
     try:
         spec, cap = geometry(cell, n_slabs, n_model, topology=topology)
-        cfg = dataclasses.replace(cell.cfg, impl=impl)
+        # the mesh is forced CPU host devices, so the Pallas rung lowers
+        # through the kernel interpreter (tests/test_tpu_compile.py
+        # compiles it for the TPU)
+        cfg = dataclasses.replace(cell.cfg, impl=impl, kernel_interpret=True)
         potential = None                 # make_local_md_step wraps cfg/impl
         if potential_name == "lj":
             potential = api.LJPotential(sel=tuple(cfg.sel), rcut_lj=cfg.rcut)
@@ -259,7 +263,7 @@ def lower_md_cell(cell: MDCell, impl: str, mesh, multi_pod: bool,
             # interpret-mode HLO counts the masked tiles as executed. Correct
             # the compute term by the live-tile fraction from the system
             # geometry (real neighbors = density * 4/3 pi rcut^3).
-            block_n = 128
+            block_n = fused_ops.DEFAULT_BLOCK_N
             nbr_real = cell.density * 4.0 / 3.0 * np.pi * cfg.rcut ** 3
             n_tiles = -(-nm // block_n)
             live = min(-(-int(nbr_real) // block_n), n_tiles)

@@ -1,9 +1,17 @@
 """Distributed MD driver: run the paper's protocol on whatever devices exist.
 
-  PYTHONPATH=src python -m repro.launch.md_run --slabs 4 --model-axis 2 \
-      --nx 8 --steps 99
-  PYTHONPATH=src python -m repro.launch.md_run --topology 2x2x2 \
-      --nx 6 --nyz 6 --steps 99
+  PYTHONPATH=src python -m repro.launch.md_run --nx 10 --nyz 10 \
+      --impl cheb_pallas --steps 40              # one chip, paper's copper
+  PYTHONPATH=src python -m repro.launch.md_run --config toy --slabs 4 \
+      --model-axis 2 --nx 8 --steps 99           # CPU smoke, toy net
+  PYTHONPATH=src python -m repro.launch.md_run --config toy \
+      --topology 2x2x2 --nx 6 --nyz 6 --steps 99
+
+``--config`` picks the model: the paper's ``copper`` (default) or
+``water`` (``configs/dpmd_*``: published widths, seeded random weights) or
+a ``toy`` copper net (rcut 4, sel 96) small enough for CPU smoke runs.
+Copper runs on an FCC lattice of ``nx x nyz x nyz`` cells, water on
+replicated 64-molecule cells.
 
 Uses the shard_map'd brick-decomposition step (staged per-axis halo sweeps
 + reverse force comm + model-axis decomposition). ``--topology`` picks the
@@ -22,7 +30,8 @@ device at ``--model-axis 1``); ``--slabs k`` is the legacy 1-D spelling
 On a single device both degenerate to 1 slab x 1 shard of the same program.
 
 The force model and the thermostat plug in through the composable
-simulation API (``--potential dp|quintic|cheb|lj``, ``--ensemble
+simulation API (``--potential dp|quintic|cheb|lj``, ``--impl`` picks the
+DP rung up to the fused Pallas kernel ``cheb_pallas``, ``--ensemble
 nve|nvt_langevin|berendsen``): the same scanned programs run the DP ladder
 or the near-free analytic LJ, NVE or thermostatted, single-process or
 slab-decomposed.
@@ -36,15 +45,38 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.configs import dpmd_copper, dpmd_water
 from repro.core.types import DPConfig
+from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.md import api, domain, integrator, lattice, stepper
 from repro.md.topology import Topology
+
+#: small copper net for CPU smoke runs (not a published model)
+TOY_COPPER = DPConfig(ntypes=1, rcut=4.0, rcut_smth=2.0, sel=(96,),
+                      type_map=("Cu",), embed_widths=(8, 16, 32),
+                      axis_neuron=4, fit_widths=(32, 32, 32))
+CONFIGS = {"copper": dpmd_copper.CONFIG, "water": dpmd_water.CONFIG,
+           "toy": TOY_COPPER}
+
+
+def build_system(config: str, nx: int, nyz: int):
+    """(pos, typ, box) for a config: water cells for water, FCC otherwise."""
+    if config == "water":
+        return lattice.water_box(nx, nyz, nyz)
+    return lattice.fcc_copper(nx, nyz, nyz)
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--nx", type=int, default=8, help="FCC cells along x")
-    ap.add_argument("--nyz", type=int, default=3, help="FCC cells along y/z (>=3: min-image needs box >= 2*rcut_halo)")
+    ap.add_argument("--config", default="copper", choices=tuple(CONFIGS),
+                    help="DP model: the paper's copper or water at "
+                         "published width, or the toy net for CPU smoke")
+    ap.add_argument("--nx", type=int, default=8,
+                    help="lattice cells along x (FCC, or 64-molecule water)")
+    ap.add_argument("--nyz", type=int, default=3,
+                    help="lattice cells along y/z (min-image needs box >= "
+                         "2*rcut_halo)")
     ap.add_argument("--slabs", type=int, default=None,
                     help="spatial slabs (default: n_devices / model_axis); "
                          "legacy 1-D spelling of --topology k")
@@ -54,14 +86,15 @@ def main(argv=None):
                          "box[a]/shape[a] >= rcut_halo must hold")
     ap.add_argument("--model-axis", type=int, default=1)
     ap.add_argument("--steps", type=int, default=99)
-    ap.add_argument("--dt", type=float, default=1.0)
+    ap.add_argument("--dt", type=float, default=None,
+                    help="timestep (fs); default 0.5 for water, 1 otherwise")
     ap.add_argument("--temp", type=float, default=330.0)
     ap.add_argument("--rebuild-every", type=int, default=20)
     ap.add_argument("--engine", default="outer", choices=("outer", "scan"))
     ap.add_argument("--chunk-segments", type=int, default=8,
                     help="outer engine: rebuild segments fused per dispatch")
     ap.add_argument("--impl", default="mlp",
-                    choices=("mlp", "quintic", "cheb"))
+                    choices=("mlp", "quintic", "cheb", "cheb_pallas"))
     ap.add_argument("--potential", default="dp",
                     choices=api.POTENTIAL_CHOICES,
                     help="force model (lj needs no DP params at all)")
@@ -80,6 +113,9 @@ def main(argv=None):
     ap.add_argument("--ptau", type=float, default=500.0,
                     help="barostat time constant (fs)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    dt = args.dt if args.dt is not None else \
+        (0.5 if args.config == "water" else 1.0)
 
     n_dev = len(jax.devices())
     if args.topology:
@@ -91,9 +127,8 @@ def main(argv=None):
         topo = Topology((k,)) if k >= 2 else None
     n_slabs = topo.n_ranks if topo is not None else 1
 
-    cfg = DPConfig(ntypes=1, rcut=4.0, rcut_smth=2.0, sel=(96,),
-                   type_map=("Cu",), embed_widths=(8, 16, 32), axis_neuron=4,
-                   fit_widths=(32, 32, 32))
+    cfg = CONFIGS[args.config]
+    masses_t = tuple(lattice.MASS[t] for t in cfg.type_map)
     # resolve_ensemble owns the coupling policy: npt_* names expand to a
     # thermostat + barostat pair, and an explicit --pressure attaches a
     # Berendsen barostat to any ensemble (same as SimulationSpec)
@@ -113,14 +148,14 @@ def main(argv=None):
         # no decomposition to exercise — the single-process driver is the
         # right tool (the slab machinery assumes >= 2 slabs so that ghost
         # images never alias their owners).
-        from repro.md import driver
-        pos, typ, box = lattice.fcc_copper(args.nx, args.nyz, args.nyz)
+        pos, typ, box = build_system(args.config, args.nx, args.nyz)
         sim = api.SimulationSpec(
             potential=potential, ensemble=ensemble, steps=args.steps,
-            dt_fs=args.dt, temp_k=args.temp, skin=0.5,
+            dt_fs=dt, temp_k=args.temp, skin=0.5,
             rebuild_every=args.rebuild_every, thermo_every=33,
+            engine=args.engine, chunk_segments=args.chunk_segments,
             barostat=barostat)
-        res = driver.run_simulation(sim, params, pos, typ, box)
+        res = api.Simulation(sim).run(params, pos, typ, box)
         for row in res.thermo:
             print(f"step {row['step']:4d}  E_pot {row['pe']:+.4f}  "
                   f"E_tot {row['etot']:+.4f}  T {row['temp']:.0f} K")
@@ -128,9 +163,9 @@ def main(argv=None):
               f"(single process, {res.n_atoms} atoms)")
         return
 
-    mesh = jax.make_mesh((n_slabs, args.model_axis), ("data", "model"))
+    mesh = mesh_lib.make_mesh((n_slabs, args.model_axis), ("data", "model"))
 
-    pos, typ, box = lattice.fcc_copper(args.nx, args.nyz, args.nyz)
+    pos, typ, box = build_system(args.config, args.nx, args.nyz)
     rng = np.random.default_rng(0)
     pos = np.mod(pos + rng.normal(0, 0.02, pos.shape), box)
     n = len(pos)
@@ -145,7 +180,7 @@ def main(argv=None):
                              topology=topo.shape)
     spec.validate()
 
-    masses = jnp.full((n,), 63.546)
+    masses = jnp.asarray(lattice.masses_for(cfg.type_map, typ))
     vel = integrator.init_velocities(jax.random.PRNGKey(1), masses, args.temp)
     state, ovf = domain.partition_atoms(
         pos.astype(np.float32), np.asarray(vel, np.float32), typ, spec)
@@ -183,7 +218,7 @@ def main(argv=None):
 
         def build_program(spec_run):
             return domain.make_outer_md_program(
-                cfg, spec_run, mesh, (63.546,), args.dt, impl=args.impl,
+                cfg, spec_run, mesh, masses_t, dt, impl=args.impl,
                 decomp="atoms", neighbor="cells", potential=potential,
                 ensemble=ensemble, barostat=barostat)
 
@@ -235,7 +270,7 @@ def main(argv=None):
             base += n_segs * seg_len
     else:
         step = domain.make_distributed_md_step(
-            cfg, spec, mesh, (63.546,), args.dt, impl=args.impl,
+            cfg, spec, mesh, masses_t, dt, impl=args.impl,
             decomp="atoms", neighbor="cells", potential=potential,
             ensemble=ensemble, barostat=barostat)
         run_segment = domain.make_segment_runner(step)

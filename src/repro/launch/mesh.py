@@ -15,19 +15,32 @@ Axis semantics:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.
+
+    The repo places arrays with ``NamedSharding`` / ``device_put`` and
+    constrains them with ``with_sharding_constraint``; both assume Auto
+    axes, while ``jax.make_mesh`` defaults to Explicit ones.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Mesh over whatever devices exist (tests / single-host runs)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return make_mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

@@ -52,25 +52,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                         # jax >= 0.5 public API
-    from jax import shard_map as _shard_map
-except ImportError:                          # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.types import DPConfig
 from repro.md import api, integrator, neighbors
 from repro.md.topology import Topology
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-compatible shard_map (check_vma was check_rep before 0.6)."""
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if check_vma is None:
-        return _shard_map(f, **kw)
-    try:
-        return _shard_map(f, check_vma=check_vma, **kw)
-    except TypeError:
-        return _shard_map(f, check_rep=check_vma, **kw)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,9 +441,12 @@ def make_local_md_step(cfg: DPConfig, spec: DomainSpec, mesh: Mesh,
     nbr_fn = None
     if neighbor == "cells":
         from repro.md import slab_cells
+        # densest a brick gets: its atom capacity over its launch volume
+        brick_volume = float(np.prod(spec.box)) / spec.n_slabs
         nbr_fn = slab_cells.make_slab_neighbor_fn(
             cfg_layout, spec.box, spec.slab_width, spec.rcut_halo, n_centers,
-            topology=spec.topology)
+            topology=spec.topology,
+            max_density=spec.atom_capacity / brick_volume)
 
     def slot_energy(pos_all, eps, nlist_slice, typ_all, mask_local, params,
                     boxm):
@@ -709,11 +696,12 @@ def make_distributed_md_step(cfg: DPConfig, spec: DomainSpec, mesh: Mesh,
 
     state_spec = _state_pspec(spatial_axis)
     thermo_spec = {k: P() for k in THERMO_KEYS}
-    return shard_map(step, mesh=mesh,
-                     in_specs=(P(), state_spec, P(spatial_axis), P(), P()),
-                     out_specs=((state_spec, P(spatial_axis), P(), P()),
-                                thermo_spec),
-                     check_vma=False)
+    # jitted: an eager shard_map runs op by op
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh,
+        in_specs=(P(), state_spec, P(spatial_axis), P(), P()),
+        out_specs=((state_spec, P(spatial_axis), P(), P()), thermo_spec),
+        check_vma=False))
 
 
 # ------------------------------------------------------- segment integration
@@ -950,8 +938,10 @@ def make_migration_step(spec: DomainSpec, mesh: Mesh,
             jax.lax.pmax(jnp.max(ovf), spatial_axis)
 
     state_spec = _state_pspec(spatial_axis)
-    sharded = shard_map(migrate, mesh=mesh, in_specs=(state_spec, P()),
-                        out_specs=(state_spec, P()), check_vma=False)
+    sharded = jax.jit(jax.shard_map(migrate, mesh=mesh,
+                                    in_specs=(state_spec, P()),
+                                    out_specs=(state_spec, P()),
+                                    check_vma=False))
 
     def migrate_entry(state: SlabState, box=None):
         from repro.md import stepper
@@ -1065,12 +1055,12 @@ class OuterMDProgram:
             return (new_state, jax.tree.map(lambda x: x[None], ens_l),
                     box, baro, th)
 
-        return shard_map(program, mesh=self._mesh,
-                         in_specs=(P(), self.state_pspec, P(spatial_axis),
-                                   P(), P()),
-                         out_specs=(self.state_pspec, P(spatial_axis),
-                                    P(), P(), self.thermo_pspec),
-                         check_vma=False)
+        return jax.shard_map(program, mesh=self._mesh,
+                             in_specs=(P(), self.state_pspec,
+                                       P(spatial_axis), P(), P()),
+                             out_specs=(self.state_pspec, P(spatial_axis),
+                                        P(), P(), self.thermo_pspec),
+                             check_vma=False)
 
     def run(self, state: SlabState, params, n_segments: int, seg_len: int,
             ens=(), box=None, baro=()):

@@ -118,6 +118,16 @@ def run_md(cfg: DPConfig, params: Any, pos: np.ndarray, typ: np.ndarray,
     return run_simulation(spec, params, pos, typ, box)
 
 
+def neighbor_spec(pot: api.Potential, skin: float, n_atoms: int,
+                  box: np.ndarray) -> neighbors.NeighborSpec:
+    """The neighbor layout a run starts from: ``pot``'s cutoff plus
+    ``skin``, its ``sel``, and cell bins sized for ``n_atoms`` in ``box``."""
+    rcut_nbr = pot.rcut + skin
+    return neighbors.NeighborSpec(
+        rcut_nbr=rcut_nbr, sel=pot.sel,
+        cell_capacity=neighbors.cell_capacity_for(n_atoms, box, rcut_nbr))
+
+
 def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
                    typ: np.ndarray, box: np.ndarray) -> MDResult:
     """Run ``spec`` on ``(params, pos, typ, box)`` — the one MD entry point.
@@ -133,9 +143,8 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
     pot, ens_obj, baro = spec.potential, spec.ensemble, spec.barostat
     n = len(pos)
     masses = jnp.asarray(lattice.masses_for(pot.type_map, np.asarray(typ)))
-    nspec = neighbors.NeighborSpec(rcut_nbr=pot.rcut + spec.skin,
-                                   sel=pot.sel)
     box_np = stepper.box_lengths(box)
+    nspec = neighbor_spec(pot, spec.skin, n, box_np)
 
     pos = jnp.asarray(pos, jnp.float32)
     typ = jnp.asarray(typ, jnp.int32)

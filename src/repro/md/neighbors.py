@@ -43,10 +43,41 @@ class NeighborSpec:
 GRID_INVALID = np.int32(1 << 20)
 
 
-def _min_image(rij: jax.Array, box: Optional[jax.Array]) -> jax.Array:
-    if box is None:
-        return rij
-    return rij - box * jnp.round(rij / box)
+def cell_capacity_for(n_atoms: int, box, rcut_nbr: float,
+                      floor: int = 64) -> int:
+    """Cell-bin capacity for ``n_atoms`` in ``box`` on the grid that
+    :func:`make_cell_list_fn` builds: 1.5x the mean atoms per cell (room
+    for thermal density fluctuations), rounded up to 8, at least ``floor``.
+
+    Sizing the bins from the system keeps the first build from overflowing
+    them: an overflow escalates ``sel`` together with the bins, and at
+    copper width (~150 atoms per 10 A cell against a 64-slot default) three
+    escalations took ``sel`` from 512 to 2112 slots.
+    """
+    ncell = np.maximum(np.floor(np.asarray(box, float) / rcut_nbr), 1)
+    mean = n_atoms / float(np.prod(ncell))
+    return max(int(floor), 8 * int(np.ceil(1.5 * mean / 8)))
+
+
+def pair_dist2(pos: jax.Array, centers: jax.Array, cand: jax.Array,
+               box: Optional[jax.Array]) -> jax.Array:
+    """Squared min-image distance from each center i to its candidates j.
+
+    ``centers`` is (N, 3); ``cand`` is (N, C) indices into ``pos`` with -1
+    for no candidate (the result there is garbage; callers mask it). Built
+    one coordinate at a time so that every temporary is (N, C) with the
+    candidate axis minor: an (N, C, 3) array pads its trailing 3 to 128
+    lanes on the TPU, a 40x blow-up that does not fit the chip at 16k
+    atoms.
+    """
+    j = cand.clip(0)
+    d2 = jnp.zeros(cand.shape, pos.dtype)
+    for a in range(3):
+        d = pos[:, a][j] - centers[:, a][:, None]
+        if box is not None:
+            d = d - box[a] * jnp.round(d / box[a])
+        d2 = d2 + d * d
+    return d2
 
 
 def pack_type_sections(
@@ -101,9 +132,8 @@ def _brute_force_neighbors(
 
     Un-jitted traceable form — embeddable inside a ``lax.scan`` body."""
     n = pos.shape[0]
-    rij = _min_image(pos[None, :, :] - pos[:, None, :], box)
-    d2 = jnp.sum(rij * rij, axis=-1)
     cand = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (n, n))
+    d2 = pair_dist2(pos, pos, cand, box)
     self_mask = jnp.eye(n, dtype=bool)
     valid = ~self_mask
     if amask is not None:
@@ -201,8 +231,7 @@ def make_cell_list_fn(spec: NeighborSpec, box: np.ndarray, jit: bool = True,
         self_mask = cand == jnp.arange(n, dtype=jnp.int32)[:, None]
         cand = jnp.where(self_mask, -1, cand)
 
-        rij = _min_image(pos[cand.clip(0)] - pos[:, None, :], box_t)
-        d2 = jnp.where(cand >= 0, jnp.sum(rij * rij, axis=-1), jnp.inf)
+        d2 = jnp.where(cand >= 0, pair_dist2(pos, pos, cand, box_t), jnp.inf)
         ctype = atype[cand.clip(0)]
         nlist, sec_overflow = _pack_sections(
             cand, d2, ctype, spec, spec.rcut_nbr**2)

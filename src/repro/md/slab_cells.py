@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import DPConfig
-from repro.md.neighbors import GRID_INVALID, pack_type_sections
+from repro.md.neighbors import GRID_INVALID, pack_type_sections, pair_dist2
 
 
 def _allowed(n: int, periodic: bool):
@@ -34,7 +34,8 @@ def _allowed(n: int, periodic: bool):
 def make_slab_neighbor_fn(cfg: DPConfig, box: Tuple[float, float, float],
                           slab_width: float, rc_halo: float,
                           n_centers: int, cell_capacity: int = 96,
-                          topology: Optional[Tuple[int, ...]] = None):
+                          topology: Optional[Tuple[int, ...]] = None,
+                          max_density: Optional[float] = None):
     """Neighbor lists for ``n_centers`` center atoms of a brick array.
 
     Returns fn(pos_all, typ_all, mask_all, brick_lo, center_start,
@@ -53,6 +54,9 @@ def make_slab_neighbor_fn(cfg: DPConfig, box: Tuple[float, float, float],
     carried box shrinks until a cell dimension no longer covers
     ``rc_halo`` (the stencil would miss pairs), the overflow flag returns
     ``>= GRID_INVALID`` — geometry, not capacity.
+
+    ``max_density`` (atoms per A^3, an upper bound for the brick) grows
+    ``cell_capacity`` to hold a cell of this grid at that density.
     """
     rc2 = rc_halo * rc_halo
     shape = tuple(int(s) for s in topology) if topology is not None else None
@@ -77,6 +81,9 @@ def make_slab_neighbor_fn(cfg: DPConfig, box: Tuple[float, float, float],
         cs0.append(span / nc)
     ncx, ncy, ncz = ncs
     ncells = ncx * ncy * ncz
+    if max_density is not None:
+        need = max_density * float(np.prod(cs0))
+        cell_capacity = max(cell_capacity, 8 * int(np.ceil(need / 8)))
 
     offsets = np.array([
         (ox, oy, oz)
@@ -174,9 +181,8 @@ def make_slab_neighbor_fn(cfg: DPConfig, box: Tuple[float, float, float],
         # norm has a NaN gradient that survives the energy mask (0 * nan).
         center_mask = jax.lax.dynamic_slice_in_dim(mask_all, start,
                                                    n_centers, 0)
-        rij = pos_all[cand.clip(0)] - center_pos[:, None, :]
-        rij = rij - boxj * jnp.round(rij / boxj)
-        d2 = jnp.where(cand >= 0, jnp.sum(rij * rij, -1), jnp.inf)
+        d2 = jnp.where(cand >= 0,
+                       pair_dist2(pos_all, center_pos, cand, boxj), jnp.inf)
         ctype = typ_all[cand.clip(0)]
 
         valid = (cand >= 0) & (d2 < rc2) & center_mask[:, None]

@@ -360,8 +360,9 @@ class OuterEngine:
         self._donate = default_donate() if donate is None else donate
         self._jits: Dict[Tuple[int, int], Any] = {}
 
-    def run(self, carry: Any, n_segments: int, seg_len: int, *aux: Any):
-        """Returns (carry, seg_out stacked with leading (n_segments,))."""
+    def jitted(self, n_segments: int, seg_len: int):
+        """The jitted ``(carry, *aux) -> (carry, seg_out)`` chunk program
+        (what :meth:`run` calls; ``.lower`` it to compile ahead of time)."""
         key = (n_segments, seg_len)
         fn = self._jits.get(key)
         if fn is None:
@@ -373,7 +374,11 @@ class OuterEngine:
             fn = jax.jit(run_chunk,
                          donate_argnums=(0,) if self._donate else ())
             self._jits[key] = fn
-        return fn(carry, *aux)
+        return fn
+
+    def run(self, carry: Any, n_segments: int, seg_len: int, *aux: Any):
+        """Returns (carry, seg_out stacked with leading (n_segments,))."""
+        return self.jitted(n_segments, seg_len)(carry, *aux)
 
 
 @functools.lru_cache(maxsize=None)

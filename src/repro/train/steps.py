@@ -26,15 +26,10 @@ class TrainState(NamedTuple):
 
 
 def init_train_state(api: ModelAPI, opt: AdamW, key: jax.Array) -> TrainState:
-    # Partitionable threefry makes the random init SHARDING-INVARIANT: with
-    # the legacy RNG (jax_threefry_partitionable=False, the 0.4.x default),
-    # jitting this function with sharded out_shardings changes the sampled
-    # values per mesh shape — FSDP and single-device runs then train
-    # *different models* from step 0 (root cause of the former
-    # test_fsdp_train_matches_single_device xfail; psum ordering was
-    # innocent). Scoped here so init is identical on any mesh.
-    with jax.threefry_partitionable(True):
-        params = api.init(key)
+    # JAX's default partitionable threefry keeps the random init
+    # sharding-invariant: FSDP and single-device runs train the same model
+    # from step 0 (tests/distributed/run_lm_dist.py checks it bit-exact).
+    params = api.init(key)
     return TrainState(params=params, opt=opt.init(params),
                       step=jnp.zeros((), jnp.int32))
 

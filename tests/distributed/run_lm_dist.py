@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.data.tokens import TokenPipeline
+from repro.launch import mesh as mesh_lib
 from repro.models import build
 from repro.sharding import ctx as sh_ctx
 from repro.sharding import plans as plans_mod
@@ -52,9 +53,9 @@ def main():
     opt = optim.AdamW(lr=lambda s: 1e-3)
     pipe = TokenPipeline(vocab=cfg.vocab, seq_len=32, global_batch=8)
 
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))
-    mesh_1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_a = mesh_lib.make_mesh((4, 2), ("data", "model"))
+    mesh_b = mesh_lib.make_mesh((2, 4), ("data", "model"))
+    mesh_1 = mesh_lib.make_mesh((1, 1), ("data", "model"))
 
     # init sharding-invariance regression (root cause of the former drift)
     jitted_a0, state_sh_a0, _, _ = build_step(cfg, api, opt, mesh_a)

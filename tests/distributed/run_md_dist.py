@@ -18,6 +18,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro.core import DPConfig, init_dp_params, dp_energy_forces
 from repro.md import api, lattice, neighbors, domain, integrator
+from repro.launch import mesh as mesh_lib
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 def main():
@@ -37,7 +38,7 @@ def main():
     f_ref = np.asarray(f_ref)
     w_ref = np.asarray(w_ref)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
     dspec = domain.DomainSpec(box=tuple(box), n_slabs=4, atom_capacity=48,
                               halo_capacity=40, rcut_halo=4.5)
     state0, ovf = domain.partition_atoms(
@@ -300,7 +301,7 @@ def brick_checks():
     f_ref = np.asarray(f_ref)
     w_ref = np.asarray(w_ref)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
     dspec = domain.DomainSpec.for_topology(
         tuple(box), (2, 2), atom_capacity=96, halo_capacity=96,
         rcut_halo=4.5)
@@ -383,7 +384,7 @@ def _lj_dist_protocol(topology, mesh_shape, pos, typ, box, vel, ensemble,
     cfg = DPConfig(ntypes=1, rcut=4.0, rcut_smth=2.0, sel=(64,),
                    type_map=("Cu",))
     lj = api.LJPotential(sel=(64,), rcut_lj=4.0)
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = mesh_lib.make_mesh(mesh_shape, ("data", "model"))
     dspec = domain.DomainSpec.for_topology(
         tuple(box), topology, atom_capacity=160, halo_capacity=256,
         rcut_halo=4.5)
@@ -491,7 +492,7 @@ def squeeze_escalation_check():
     n = len(pos)
     masses = jnp.full((n,), 63.546)
     vel = integrator.init_velocities(jax.random.PRNGKey(4), masses, 330.0)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 
     # the affine squeeze a barostat run would produce: box AND positions
     f = 0.8                                     # volume ratio 1/f^3 ~ 1.95

@@ -1,5 +1,7 @@
 """dp_fused Pallas kernel: shape/dtype sweeps + grads vs the ref.py oracle,
-including hypothesis-generated ragged neighbor counts."""
+including hypothesis-generated ragged neighbor counts. Every call runs the
+kernel in interpret mode; tests/test_tpu_compile.py compiles it for the
+TPU."""
 
 
 import jax
@@ -12,6 +14,11 @@ from repro.kernels.dp_fused import ops as fused_ops
 from repro.kernels.dp_fused import ref as fused_ref
 
 LOWER, UPPER = -1.0, 9.0
+
+
+def _fused(env, s, coeffs, **kw):
+    return fused_ops.fused_env_tab_contract(env, s, coeffs, LOWER, UPPER,
+                                            interpret=True, **kw)
 
 
 def _mk_inputs(key, a, n, k, m, dtype, counts=None):
@@ -33,7 +40,7 @@ def _mk_inputs(key, a, n, k, m, dtype, counts=None):
 @pytest.mark.parametrize("dtype", [jnp.float32])
 def test_fused_matches_oracle(a, n, k, m, dtype):
     s, env, coeffs = _mk_inputs(jax.random.PRNGKey(0), a, n, k, m, dtype)
-    out = fused_ops.fused_env_tab_contract(env, s, coeffs, LOWER, UPPER)
+    out = _fused(env, s, coeffs)
     ref = fused_ref.fused_env_tab_contract_ref(env, s, coeffs, LOWER, UPPER)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
@@ -44,7 +51,7 @@ def test_fused_batch_dims():
                                 jnp.float32)
     s3 = s.reshape(3, 4, 64)
     env3 = env.reshape(3, 4, 64, 4)
-    out = fused_ops.fused_env_tab_contract(env3, s3, coeffs, LOWER, UPPER)
+    out = _fused(env3, s3, coeffs)
     assert out.shape == (3, 4, 4, 32)
     ref = fused_ref.fused_env_tab_contract_ref(env3, s3, coeffs, LOWER, UPPER)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
@@ -56,7 +63,7 @@ def test_fused_grads_match_oracle_grads():
                                 jnp.float32)
 
     def loss_kernel(env, s):
-        out = fused_ops.fused_env_tab_contract(env, s, coeffs, LOWER, UPPER)
+        out = _fused(env, s, coeffs)
         return jnp.sum(jnp.sin(out))
 
     def loss_ref(env, s):
@@ -85,7 +92,7 @@ def test_fused_ragged_counts_property(a, n_pow, counts):
     cts = counts.draw(st.lists(st.integers(0, n), min_size=a, max_size=a))
     s, env, coeffs = _mk_inputs(jax.random.PRNGKey(3), a, n, 16, 32,
                                 jnp.float32, counts=cts)
-    out = fused_ops.fused_env_tab_contract(env, s, coeffs, LOWER, UPPER)
+    out = _fused(env, s, coeffs)
     ref = fused_ref.fused_env_tab_contract_ref(env, s, coeffs, LOWER, UPPER)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5,
                                atol=2e-5)
@@ -98,11 +105,11 @@ def test_block_skipping_actually_skips():
     a, n = 8, 128
     s, env, coeffs = _mk_inputs(jax.random.PRNGKey(4), a, n, 16, 32,
                                 jnp.float32, counts=[32] * a)
+    # block_n=64 is legal only in interpret mode (the TPU needs 128)
     kw = dict(block_a=8, block_n=64)     # tiles: [0,64) live, [64,128) skipped
-    ref = fused_ops.fused_env_tab_contract(env, s, coeffs, LOWER, UPPER, **kw)
+    ref = _fused(env, s, coeffs, **kw)
     # s==0 marks padding; env NaNs live ONLY in the fully-skipped tile
     env_poison = env.at[:, 64:, :].set(jnp.nan)
-    out = fused_ops.fused_env_tab_contract(env_poison, s, coeffs, LOWER,
-                                           UPPER, **kw)
+    out = _fused(env_poison, s, coeffs, **kw)
     assert not bool(jnp.isnan(out).any())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
