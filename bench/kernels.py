@@ -1,0 +1,12 @@
+"""How the program's kernels are named in a device trace."""
+
+import re
+
+# The dp_fused pallas_calls pass no name=; on the chip their custom calls
+# are named after the jitted wrappers around them, under autodiff:
+# jvp_jit_fused_fwd__.8 and transpose_jvp_jit_fused_bwd___.26.
+DP_FUSED = re.compile(r"(^|_)fused_(fwd|bwd)(_|\.|$)")
+
+
+def is_dp_fused(name: str) -> bool:
+    return DP_FUSED.search(name) is not None
