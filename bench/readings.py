@@ -15,6 +15,11 @@ it: a step that returns its state unchanged, and (on the first
 precision (one bf16 pass per f32 product), its own lower-precision path:
 the upper reading is the smallest control reading. One process serves
 every seed, so only the first run of each precision compiles.
+
+Each program row also gives ``v_change_rms`` (A/fs): rms |v_ref - v_0|,
+the change the reference's forces made over the window, which is the
+denominator of ``velocity``. The last line summarises each side: the
+least and the largest reading of each number, each with its seed.
 """
 
 import time
@@ -39,6 +44,18 @@ def seed_list(text):
         lo, _, hi = part.partition("-")
         out.extend(range(int(lo), int(hi or lo) + 1))
     return out
+
+
+def summarize(rows):
+    """{side: {number: {"min": [value, seed], "max": [value, seed]}}}."""
+    summary = {}
+    for side in dict.fromkeys(r["side"] for r in rows):
+        got = [r for r in rows if r["side"] == side]
+        summary[side] = {
+            k: {"min": list(min((r["numbers"][k], r["seed"]) for r in got)),
+                "max": list(max((r["numbers"][k], r["seed"]) for r in got))}
+            for k in NUMBERS}
+    return summary
 
 
 def main(argv=None) -> int:
@@ -85,6 +102,7 @@ def main(argv=None) -> int:
         checks = harness.check(setup, out, cell["limits"], traj)
         moved = traj["pos"] - np.asarray(setup.pos, np.float64)
         moved -= setup.box * np.round(moved / setup.box)
+        kicked = traj["vel"] - np.asarray(setup.vel0, np.float64)
         emit({"seed": seed, "side": "program" if precision is None
               else "program@default",
               "numbers": {k: checks[k]["value"] for k in NUMBERS},
@@ -94,7 +112,9 @@ def main(argv=None) -> int:
               "window_s": rec["window_s"], "steps": rec["steps"],
               "reference_s": t_ref, "run_s": time.perf_counter() - t0,
               "max_displacement_a": float(np.sqrt(
-                  np.max(np.sum(moved * moved, axis=1))))})
+                  np.max(np.sum(moved * moved, axis=1)))),
+              "v_change_rms": float(np.sqrt(
+                  np.mean(np.sum(kicked * kicked, axis=1))))})
         if precision is not None:
             continue
         faults = [("unchanged", harness.unchanged_trajectory(
@@ -108,13 +128,7 @@ def main(argv=None) -> int:
             emit({"seed": seed, "side": f"fault:{name}",
                   "numbers": {k: c[k]["value"] for k in NUMBERS},
                   "passed": harness.passed(c)})
-    summary = {}
-    for side in dict.fromkeys(r["side"] for r in rows):
-        got = [r["numbers"] for r in rows if r["side"] == side]
-        if got:
-            summary[side] = {k: (min(g[k] for g in got), max(g[k] for g in got))
-                             for k in got[0]}
-    print(json.dumps({"summary": summary,
+    print(json.dumps({"summary": summarize(rows),
                       "total_s": time.perf_counter() - T_START}), flush=True)
     return 0
 

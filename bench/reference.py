@@ -228,14 +228,17 @@ def energy_forces_virial(params, model, pos, typ, box, lists,
 
 def integrate(params, model, pos, vel, typ, box, masses, dt: float,
               steps: int, prec: str = "highest", skin: float = 1.0,
-              kick_sign: float = 1.0, block: int = 512) -> Dict[str, Any]:
+              kick_sign: float = 1.0, block: int = 512,
+              state=np.float64) -> Dict[str, Any]:
     """Velocity Verlet (half kick, drift, force, half kick) for ``steps``
     steps of ``dt`` fs from the frame ``(pos, vel)``, in float64 on the
     host with the forces above. Neighbors come from a brute-force list out
     to rcut + ``skin``, built again whenever an atom has moved more than
     ``skin / 2`` since the last build; pairs past rcut add exactly nothing.
     Positions stay wrapped into the box. ``kick_sign`` -1 flips the kicks
-    (a broken integrator, for the checks' faults). Returns the final
+    (a broken integrator, for the checks' faults). ``state`` np.float32
+    rounds positions and velocities to float32 after every update, as a
+    float32 program keeps them (a witness, not the check). Returns the final
     ``pos``, ``vel``, ``force`` and ``virial``, and ``pe`` and ``ke`` (eV)
     after every step."""
     box = np.asarray(box, np.float64)
@@ -244,8 +247,8 @@ def integrate(params, model, pos, vel, typ, box, masses, dt: float,
     ks = neighbor_capacity(model, n_of_type, float(np.prod(box)), skin)
     radius = float(model["rcut"]) + skin
     acc = FORCE_TO_ACC / np.asarray(masses, np.float64)[:, None]
-    x = np.mod(np.asarray(pos, np.float64), box)
-    v = np.asarray(vel, np.float64).copy()
+    x = np.mod(np.asarray(pos, np.float64), box).astype(state)
+    v = np.asarray(vel, np.float64).astype(state)
 
     def forces(x):
         return energy_forces_virial(params, model, x, typ, box, lists, prec,
@@ -256,15 +259,15 @@ def integrate(params, model, pos, vel, typ, box, masses, dt: float,
     e, f, w = forces(x)
     pe, ke = [], []
     for _ in range(steps):
-        v += kick_sign * 0.5 * dt * f * acc
-        x = np.mod(x + dt * v, box)
+        v = (v + kick_sign * 0.5 * dt * f * acc).astype(state)
+        x = np.mod(x + dt * v, box).astype(state)
         moved = x - x_built
         moved -= box * np.round(moved / box)
         if np.max(np.sum(moved * moved, axis=1)) > (0.5 * skin) ** 2:
             x_built = x.copy()
             lists, _ = neighbor_lists(x, typ, box, radius, ks)
         e, f, w = forces(x)
-        v += kick_sign * 0.5 * dt * f * acc
+        v = (v + kick_sign * 0.5 * dt * f * acc).astype(state)
         pe.append(e)
         ke.append(0.5 * float(np.sum(v * v / acc)))
     return {"pos": x, "vel": v, "force": f, "virial": w,
