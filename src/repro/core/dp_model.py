@@ -7,6 +7,10 @@ The implementation ladder follows the paper's optimization story:
   impl="cheb"        + TPU-adapted Chebyshev tabulation (basis matmul)
   impl="cheb_pallas" + Sec. 3.4.1 kernel fusion and Sec. 3.4.2 redundancy
                        removal (Pallas kernel; G never materialized)
+
+Each sublayer runs under a ``jax.named_scope`` that profiler traces show
+on its device ops: ``dp.env``, ``dp.embed``, ``dp.fitting`` and
+``dp.scatter`` (autodiff names their backward ``transpose(jvp(dp.*))``).
 """
 
 from __future__ import annotations
@@ -129,24 +133,27 @@ def dp_atomic_energy(params: Dict[str, Any], cfg: DPConfig, rij: jax.Array,
         cfg.sel is a per-shard slice.
     """
     impl = impl or cfg.impl
-    env, s = descriptor.env_matrix(rij, nmask, cfg.rcut_smth, cfg.rcut)
-    env_n, s_n = descriptor.normalize_env(env, s, atype, params["dstd"])
+    with jax.named_scope("dp.env"):
+        env, s = descriptor.env_matrix(rij, nmask, cfg.rcut_smth, cfg.rcut)
+        env_n, s_n = descriptor.normalize_env(env, s, atype, params["dstd"])
 
-    if cfg.ntypes == 1 or cfg.type_one_side:
-        t_mat = _t_matrix_onetype(params, cfg, impl, 0, env_n, s_n)
-    else:
-        t_mat = None
-        for ct in range(cfg.ntypes):
-            t_ct = _t_matrix_onetype(params, cfg, impl, ct, env_n, s_n)
-            sel = (atype == ct)[..., None, None]
-            t_mat = jnp.where(sel, t_ct, t_mat) if t_mat is not None else jnp.where(sel, t_ct, 0.0)
+    with jax.named_scope("dp.embed"):
+        if cfg.ntypes == 1 or cfg.type_one_side:
+            t_mat = _t_matrix_onetype(params, cfg, impl, 0, env_n, s_n)
+        else:
+            t_mat = None
+            for ct in range(cfg.ntypes):
+                t_ct = _t_matrix_onetype(params, cfg, impl, ct, env_n, s_n)
+                sel = (atype == ct)[..., None, None]
+                t_mat = jnp.where(sel, t_ct, t_mat) if t_mat is not None else jnp.where(sel, t_ct, 0.0)
 
     if axis_name is not None:
         t_mat = jax.lax.psum(t_mat, axis_name)
-    d = descriptor.descriptor_from_t(t_mat, cfg.axis_neuron,
-                                     nsel_norm or cfg.nsel)
-    e_i = fitting.fitting_energy(params["fit"], cfg, d, atype)
-    return e_i + params["ebias"][atype]
+    with jax.named_scope("dp.fitting"):
+        d = descriptor.descriptor_from_t(t_mat, cfg.axis_neuron,
+                                         nsel_norm or cfg.nsel)
+        e_i = fitting.fitting_energy(params["fit"], cfg, d, atype)
+        return e_i + params["ebias"][atype]
 
 
 def dp_energy(params: Dict[str, Any], cfg: DPConfig, rij: jax.Array,
@@ -156,7 +163,8 @@ def dp_energy(params: Dict[str, Any], cfg: DPConfig, rij: jax.Array,
     """Total energy E = sum_i E_i over valid atoms."""
     e_i = dp_atomic_energy(params, cfg, rij, nmask, atype, impl,
                            nsel_norm=nsel_norm)
-    return jnp.sum(e_i * amask, axis=(-1,))
+    with jax.named_scope("dp.fitting"):
+        return jnp.sum(e_i * amask, axis=(-1,))
 
 
 def gather_rij(pos: jax.Array, nlist: jax.Array, box: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
@@ -198,14 +206,17 @@ def dp_energy_forces(params: Dict[str, Any], cfg: DPConfig, pos: jax.Array,
         return dp_energy(params, cfg, rij, nmask, atype, amask, impl,
                          nsel_norm=nsel_norm)
 
-    rij, nmask = gather_rij(pos, nlist, box)
+    with jax.named_scope("dp.env"):
+        rij, nmask = gather_rij(pos, nlist, box)
     e, de_drij = jax.value_and_grad(e_of_rij)(rij, nmask)
 
-    # Pair forces: f_ij = -dE/dr_ij acts on atom j, reaction +dE/dr_ij on i.
-    f = jnp.zeros_like(pos)
-    nmaskf = nmask[..., None].astype(de_drij.dtype)
-    f = f.at[jnp.maximum(nlist, 0)].add(-de_drij * nmaskf)
-    f = f + jnp.sum(de_drij * nmaskf, axis=1)
+    with jax.named_scope("dp.scatter"):
+        # Pair forces: f_ij = -dE/dr_ij acts on atom j, reaction +dE/dr_ij
+        # on i.
+        f = jnp.zeros_like(pos)
+        nmaskf = nmask[..., None].astype(de_drij.dtype)
+        f = f.at[jnp.maximum(nlist, 0)].add(-de_drij * nmaskf)
+        f = f + jnp.sum(de_drij * nmaskf, axis=1)
 
-    virial = -jnp.einsum("ijk,ijl->kl", rij, de_drij * nmaskf)
+        virial = -jnp.einsum("ijk,ijl->kl", rij, de_drij * nmaskf)
     return e, f, virial

@@ -7,6 +7,9 @@
   PYTHONPATH=src python -m repro.launch.md_run --config toy \
       --topology 2x2x2 --nx 6 --nyz 6 --steps 99
 
+``--profile DIR`` runs the simulation under ``jax.profiler.trace(DIR)``;
+the README's "Tracing a run" names the scopes, spans and counters it shows.
+
 ``--config`` picks the model: the paper's ``copper`` (default) or
 ``water`` (``configs/dpmd_*``: published widths, seeded random weights) or
 a ``toy`` copper net (rcut 4, sel 96) small enough for CPU smoke runs.
@@ -38,6 +41,7 @@ slab-decomposed.
 """
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -112,8 +116,20 @@ def main(argv=None):
                          "SimulationSpec.pressure_gpa behavior)")
     ap.add_argument("--ptau", type=float, default=500.0,
                     help="barostat time constant (fs)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="run the simulation under jax.profiler.trace(DIR): "
+                         "the device ops carry the program's md.*/dp.* "
+                         "scopes, the host its md.* spans")
     args = ap.parse_args(argv)
     enable_compile_cache()
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
+    with profile:
+        simulate(args)
+
+
+def simulate(args):
+    """Build the system and run it as ``args`` ask (see :func:`main`)."""
     dt = args.dt if args.dt is not None else \
         (0.5 if args.config == "water" else 1.0)
 

@@ -46,6 +46,10 @@ import numpy as np
 from repro.core.types import DPConfig
 from repro.md import api, integrator, lattice, neighbors, stepper
 
+#: Host spans on the profiler's clock (no cost without an active profiler
+#: beyond the annotation object); the names are listed in the README.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class MDResult:
@@ -63,6 +67,11 @@ class MDResult:
     final_box: Optional[np.ndarray] = None   # (3,) A — moves under a barostat
     stress: Optional[np.ndarray] = None      # (steps, 3, 3) eV/A^3 per-step
     grid_rebuilds: int = 0        # cell grids re-derived from a moved box
+    nbr_builds: int = 0           # neighbor builds, escalation retries and
+    #                               replayed chunks' in-program builds too
+    nbr_live_slots: int = 0       # live list entries (nlist >= 0) and
+    nbr_slots: int = 0            # atoms x sel, summed over the in-program
+    #                               builds the run stepped with (outer only)
 
     @property
     def us_per_step_atom(self) -> float:
@@ -128,6 +137,7 @@ def neighbor_spec(pot: api.Potential, skin: float, n_atoms: int,
         cell_capacity=neighbors.cell_capacity_for(n_atoms, box, rcut_nbr))
 
 
+@functools.partial(jax.profiler.annotate_function, name="md.run")
 def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
                    typ: np.ndarray, box: np.ndarray) -> MDResult:
     """Run ``spec`` on ``(params, pos, typ, box)`` — the one MD entry point.
@@ -160,14 +170,17 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
                               thermo_every=spec.thermo_every, barostat=baro)
 
     # ------------------------------------- fused on-device paths (scan/outer)
-    build = stepper.build_neighbors_escalating(
-        pot.layout_cfg(), nspec, box_np, pos, typ, spec.escalation,
-        dynamic_box=True)
+    with _span("md.first_build"):
+        build = stepper.build_neighbors_escalating(
+            pot.layout_cfg(), nspec, box_np, pos, typ, spec.escalation,
+            dynamic_box=True)
     escalations = build.escalations
     overflow_checks = build.escalations + 1
     overflow_worst = build.overflow
     pot_run = pot.with_layout(build.spec.sel)
-    _, f, _ = pot_run.energy_forces(params, pos, typ, build.nlist, box=boxj)
+    with _span("md.first_force"):
+        _, f, _ = pot_run.energy_forces(params, pos, typ, build.nlist,
+                                        box=boxj)
 
     if spec.engine == "outer":
         return _run_md_outer(pot, ens_obj, params, pos, vel, f, typ, boxj,
@@ -186,10 +199,11 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
     thermo: List[Dict[str, float]] = []
     stress_segs: List[np.ndarray] = []
     host_syncs = 1                      # initial build's overflow check
+    nbr_builds = build.escalations + 1
     grid_rebuilds = 0
     grid_key = stepper.grid_key_for(nspec, box_np)
     ref_box_escal = box_np      # box the last volume fold was taken against
-    t0 = time.time()
+    t0 = time.perf_counter()
     step_base = 0
     for seg_len in stepper.segment_schedule(spec.steps, spec.rebuild_every):
         if step_base > 0:
@@ -220,6 +234,7 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
                 spec.escalation, dynamic_box=True,
                 ref_box=ref_box_escal if baro is not None else None)
             host_syncs += 1
+            nbr_builds += build.escalations + 1
             overflow_checks += build.escalations + 1
             overflow_worst = max(overflow_worst, build.overflow)
             if build.escalations:
@@ -240,7 +255,7 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
         host_syncs += 1
         step_base += seg_len
     carry.pos.block_until_ready()
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     return MDResult(thermo=thermo, final_pos=np.asarray(carry.pos),
                     final_vel=np.asarray(carry.vel), wall_s=wall,
                     steps=spec.steps, n_atoms=n, engine="scan",
@@ -250,7 +265,7 @@ def run_simulation(spec: api.SimulationSpec, params: Any, pos: np.ndarray,
                     final_box=np.asarray(carry.box),
                     stress=(np.concatenate(stress_segs)
                             if stress_segs else None),
-                    grid_rebuilds=grid_rebuilds)
+                    grid_rebuilds=grid_rebuilds, nbr_builds=nbr_builds)
 
 
 def _run_md_outer(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
@@ -296,93 +311,113 @@ def _run_md_outer(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
     host_syncs = 1                      # initial build's overflow check
     overflow_checks = escalations0 + 1
     overflow_worst = build.overflow
-    t0 = time.time()
+    nbr_builds = escalations0 + 1       # the host-path builds before the loop
+    live_slots = slots = 0
+    t0 = time.perf_counter()
     step_base = 0
-    for n_segs, seg_len in stepper.chunk_schedule(steps, rebuild_every,
-                                                  chunk_segments):
-        for _ in range(policy.max_attempts + 1):
-            eng = stepper.md_outer_engine(pot_run, ens_obj, spec_n,
-                                          grid_key, donate, barostat)
-            # Chunk-entry snapshot for the escalation replay. Without
-            # donation the input carry stays valid — keeping the reference
-            # is free. With donation the inputs are consumed by the run, so
-            # copy to host first (the buffers are already synced: the
-            # previous chunk's overflow check waited on them).
-            snap = jax.device_get(carry) if donate else carry
-            out, th = eng.run(carry, n_segs, seg_len, params, typ,
-                              masses, dt_fs)
-            ovf = int(out.overflow)     # THE host sync for this chunk
-            host_syncs += 1
-            overflow_checks += 1
-            if ovf >= int(neighbors.GRID_INVALID):
-                # geometry, not capacity: the carried box outgrew the
-                # static cell grid MID-chunk — the snapshot box still maps
-                # to the old counts, so re-derive from the POST-chunk box
-                # instead (coarser counts from a smaller box keep every
-                # cell >= rcut for the chunk's larger early boxes too).
-                # A box that DIPPED below validity and recovered by chunk
-                # end reproduces the old key: coarsen one cell per dim then
-                # — larger cells buy margin, so every retry makes progress
-                # instead of replaying the identical flap to exhaustion.
-                # Growing sel would never fix this.
-                key_new = stepper.grid_key_for(spec_n,
-                                               np.asarray(out.box, float))
-                if key_new == grid_key:
-                    key_new = tuple(max(1, k - 1) for k in grid_key)
-                grid_key = key_new
-                grid_rebuilds += 1
-            else:
-                overflow_worst = max(overflow_worst, ovf)
-                if ovf <= 0:
-                    carry = out
-                    break
-                # fold the carried-box volume ratio into the growth: a
-                # barostat-compressed chunk raises the density everywhere,
-                # so the capacity jump matches it in ONE replay. Advance
-                # the reference box afterwards — a later retry (or later
-                # chunk) only folds ADDITIONAL shrink, never re-applies
-                # the same density jump multiplicatively.
-                box_out = np.asarray(out.box, float)
-                vol_scale = policy.volume_scale(ref_box_escal, box_out)
-                ref_box_escal = box_out
-                spec_n = dataclasses.replace(
-                    spec_n,
-                    sel=tuple(policy.grow(s, vol_scale) for s in spec_n.sel),
-                    cell_capacity=policy.grow(spec_n.cell_capacity,
-                                              vol_scale))
-                pot_run = pot.with_layout(spec_n.sel)
-                escalations += 1
-            carry = stepper.OuterCarry(
-                jnp.asarray(snap.pos), jnp.asarray(snap.vel),
-                jnp.asarray(snap.force), jnp.zeros((), jnp.int32),
-                jax.tree.map(jnp.asarray, snap.ens),
-                jnp.asarray(snap.box),
-                jax.tree.map(jnp.asarray, snap.baro))
+    for chunk, (n_segs, seg_len) in enumerate(
+            stepper.chunk_schedule(steps, rebuild_every, chunk_segments)):
+        for attempt in range(policy.max_attempts + 1):
+            with _span("md.chunk", chunk=chunk, attempt=attempt):
+                eng = stepper.md_outer_engine(pot_run, ens_obj, spec_n,
+                                              grid_key, donate, barostat)
+                # Chunk-entry snapshot for the escalation replay. Without
+                # donation the input carry stays valid — keeping the
+                # reference is free. With donation the inputs are consumed
+                # by the run, so copy to host first (the buffers are
+                # already synced: the previous chunk's overflow check
+                # waited on them).
+                with _span("md.snapshot"):
+                    snap = jax.device_get(carry) if donate else carry
+                with _span("md.dispatch"):
+                    out, th = eng.run(carry, n_segs, seg_len, params, typ,
+                                      masses, dt_fs)
+                nbr_builds += n_segs        # one in-program build a segment
+                with _span("md.sync"):
+                    ovf = int(out.overflow)     # THE host sync for this chunk
+                host_syncs += 1
+                overflow_checks += 1
+                if ovf >= int(neighbors.GRID_INVALID):
+                    # geometry, not capacity: the carried box outgrew the
+                    # static cell grid MID-chunk — the snapshot box still
+                    # maps to the old counts, so re-derive from the
+                    # POST-chunk box instead (coarser counts from a smaller
+                    # box keep every cell >= rcut for the chunk's larger
+                    # early boxes too). A box that DIPPED below validity
+                    # and recovered by chunk end reproduces the old key:
+                    # coarsen one cell per dim then — larger cells buy
+                    # margin, so every retry makes progress instead of
+                    # replaying the identical flap to exhaustion. Growing
+                    # sel would never fix this.
+                    key_new = stepper.grid_key_for(
+                        spec_n, np.asarray(out.box, float))
+                    if key_new == grid_key:
+                        key_new = tuple(max(1, k - 1) for k in grid_key)
+                    grid_key = key_new
+                    grid_rebuilds += 1
+                else:
+                    overflow_worst = max(overflow_worst, ovf)
+                    if ovf <= 0:
+                        carry = out
+                        # thermo and the build counters arrive stacked
+                        # (n_segs, seg_len) / (n_segs,)
+                        with _span("md.thermo_fetch"):
+                            thermo.extend(stepper.thermo_rows(
+                                np.asarray(th["pe"]).reshape(-1),
+                                np.asarray(th["ke"]).reshape(-1),
+                                step_base, steps, thermo_every, n,
+                                press=np.asarray(th["press"]).reshape(-1),
+                                vol=np.asarray(th["vol"]).reshape(-1)))
+                            stress_chunks.append(
+                                np.asarray(th["stress"]).reshape(-1, 3, 3))
+                            live = np.asarray(th["nbr_live"], np.int64)
+                        live_slots += int(live.sum())
+                        slots += live.size * n * spec_n.nsel
+                        break
+                    # fold the carried-box volume ratio into the growth: a
+                    # barostat-compressed chunk raises the density
+                    # everywhere, so the capacity jump matches it in ONE
+                    # replay. Advance the reference box afterwards — a
+                    # later retry (or later chunk) only folds ADDITIONAL
+                    # shrink, never re-applies the same density jump
+                    # multiplicatively.
+                    box_out = np.asarray(out.box, float)
+                    vol_scale = policy.volume_scale(ref_box_escal, box_out)
+                    ref_box_escal = box_out
+                    spec_n = dataclasses.replace(
+                        spec_n,
+                        sel=tuple(policy.grow(s, vol_scale)
+                                  for s in spec_n.sel),
+                        cell_capacity=policy.grow(spec_n.cell_capacity,
+                                                  vol_scale))
+                    pot_run = pot.with_layout(spec_n.sel)
+                    escalations += 1
+                carry = stepper.OuterCarry(
+                    jnp.asarray(snap.pos), jnp.asarray(snap.vel),
+                    jnp.asarray(snap.force), jnp.zeros((), jnp.int32),
+                    jax.tree.map(jnp.asarray, snap.ens),
+                    jnp.asarray(snap.box),
+                    jax.tree.map(jnp.asarray, snap.baro))
         else:
             raise RuntimeError(
                 f"neighbor capacity overflow persists after "
                 f"{policy.max_attempts} chunk replays (last spec: "
                 f"sel={spec_n.sel}, cell_capacity={spec_n.cell_capacity})")
-        # thermo for the whole chunk arrives stacked (n_segs, seg_len)
-        thermo.extend(stepper.thermo_rows(
-            np.asarray(th["pe"]).reshape(-1), np.asarray(th["ke"]).reshape(-1),
-            step_base, steps, thermo_every, n,
-            press=np.asarray(th["press"]).reshape(-1),
-            vol=np.asarray(th["vol"]).reshape(-1)))
-        stress_chunks.append(np.asarray(th["stress"]).reshape(-1, 3, 3))
         step_base += n_segs * seg_len
-    carry.pos.block_until_ready()
-    wall = time.time() - t0
-    return MDResult(thermo=thermo, final_pos=np.asarray(carry.pos),
-                    final_vel=np.asarray(carry.vel), wall_s=wall,
-                    steps=steps, n_atoms=n, engine="outer",
-                    escalations=escalations, host_syncs=host_syncs,
-                    overflow_checks=overflow_checks,
-                    overflow_worst=overflow_worst,
-                    final_box=np.asarray(carry.box),
-                    stress=(np.concatenate(stress_chunks)
-                            if stress_chunks else None),
-                    grid_rebuilds=grid_rebuilds)
+    with _span("md.final_fetch"):
+        carry.pos.block_until_ready()
+        wall = time.perf_counter() - t0
+        return MDResult(thermo=thermo, final_pos=np.asarray(carry.pos),
+                        final_vel=np.asarray(carry.vel), wall_s=wall,
+                        steps=steps, n_atoms=n, engine="outer",
+                        escalations=escalations, host_syncs=host_syncs,
+                        overflow_checks=overflow_checks,
+                        overflow_worst=overflow_worst,
+                        final_box=np.asarray(carry.box),
+                        stress=(np.concatenate(stress_chunks)
+                                if stress_chunks else None),
+                        grid_rebuilds=grid_rebuilds, nbr_builds=nbr_builds,
+                        nbr_live_slots=live_slots, nbr_slots=slots)
 
 
 def _run_md_python(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
@@ -421,7 +456,7 @@ def _run_md_python(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
     stress_steps = []
     ovf_flags = []
     grid_rebuilds = 0
-    t0 = time.time()
+    t0 = time.perf_counter()
     for step in range(steps):
         pos, vel = kick_drift(pos, vel, f, masses, dt_fs, boxj)
         if (step + 1) % rebuild_every == 0:
@@ -465,7 +500,7 @@ def _run_md_python(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
             boxj, pos, vel, baro = barostat.apply(boxj, pos, vel, stress,
                                                   baro, dt_fs)
     pos.block_until_ready()
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0
     if ovf_flags:
         # ONE deferred fetch inspects every rebuild's flag after the run.
         worst = int(jnp.max(jnp.stack(ovf_flags)))
@@ -481,4 +516,5 @@ def _run_md_python(pot: api.Potential, ens_obj: api.Ensemble, params, pos,
                     final_box=np.asarray(boxj),
                     stress=(np.asarray(jnp.stack(stress_steps))
                             if stress_steps else None),
-                    grid_rebuilds=grid_rebuilds)
+                    grid_rebuilds=grid_rebuilds,
+                    nbr_builds=len(ovf_flags) + 1)

@@ -42,6 +42,10 @@ class NeighborSpec:
 #: real capacity excess, so ``flag >= GRID_INVALID`` is unambiguous.
 GRID_INVALID = np.int32(1 << 20)
 
+#: Name scope of every neighbor build's device ops (the cell list and the
+#: brute-force path), so a profiler trace attributes them to this layer.
+SCOPE = "md.neighbors"
+
 
 def cell_capacity_for(n_atoms: int, box, rcut_nbr: float,
                       floor: int = 64) -> int:
@@ -124,6 +128,7 @@ def _pack_sections(
                               spec.sel)
 
 
+@jax.named_scope(SCOPE)
 def _brute_force_neighbors(
     pos: jax.Array, atype: jax.Array, spec: NeighborSpec,
     box: Optional[jax.Array] = None, amask: Optional[jax.Array] = None,
@@ -193,6 +198,7 @@ def make_cell_list_fn(spec: NeighborSpec, box: np.ndarray, jit: bool = True,
         np.meshgrid(*[[-1, 0, 1]] * 3, indexing="ij"), axis=-1
     ).reshape(-1, 3)                                   # (27, 3)
 
+    @jax.named_scope(SCOPE)
     def core(pos, atype, box_t, amask):
         n = pos.shape[0]
         cap = spec.cell_capacity

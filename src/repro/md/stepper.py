@@ -49,6 +49,12 @@ from repro.core.types import DPConfig
 from repro.md import api, integrator, neighbors
 
 
+#: Name scope of the integration ops of a step (kicks, drift, thermostat
+#: finalize, kinetic energy, stress, barostat): everything in the step but
+#: the force evaluation, whose layers the potential names itself.
+INTEGRATE_SCOPE = "md.integrate"
+
+
 def default_donate() -> bool:
     """Donation saves the carry copy on gpu/tpu; the cpu backend only warns."""
     return jax.default_backend() != "cpu"
@@ -272,21 +278,23 @@ def make_md_step(potential: api.Potential, ensemble: api.Ensemble,
 
     def md_step(carry: MDCarry, params, nlist, typ, masses, dt):
         pos, vel, f, ens, box, baro = carry
-        vel = ensemble.half_kick(vel, f, masses, dt)
-        pos = ensemble.drift(pos, vel, dt, box)
+        with jax.named_scope(INTEGRATE_SCOPE):
+            vel = ensemble.half_kick(vel, f, masses, dt)
+            pos = ensemble.drift(pos, vel, dt, box)
         e, f_new, stats = potential.energy_forces(params, pos, typ, nlist,
                                                   box=box)
-        vel = ensemble.half_kick(vel, f_new, masses, dt)
-        vel, ens = ensemble.finalize(vel, masses, dt, ens)
-        ke = integrator.kinetic_energy(vel, masses)
-        vol = integrator.volume_of(box)
-        stress = integrator.stress_tensor(
-            integrator.kinetic_tensor(vel, masses), stats["virial"], vol)
-        if barostat is not None:
-            box, pos, vel, baro = barostat.apply(box, pos, vel, stress,
-                                                 baro, dt)
-        thermo = {"pe": e, "ke": ke, "stress": stress,
-                  "press": integrator.pressure_of(stress), "vol": vol}
+        with jax.named_scope(INTEGRATE_SCOPE):
+            vel = ensemble.half_kick(vel, f_new, masses, dt)
+            vel, ens = ensemble.finalize(vel, masses, dt, ens)
+            ke = integrator.kinetic_energy(vel, masses)
+            vol = integrator.volume_of(box)
+            stress = integrator.stress_tensor(
+                integrator.kinetic_tensor(vel, masses), stats["virial"], vol)
+            if barostat is not None:
+                box, pos, vel, baro = barostat.apply(box, pos, vel, stress,
+                                                     baro, dt)
+            thermo = {"pe": e, "ke": ke, "stress": stress,
+                      "press": integrator.pressure_of(stress), "vol": vol}
         return MDCarry(pos, vel, f_new, ens, box, baro), thermo
 
     return md_step
@@ -405,7 +413,9 @@ def md_outer_engine(potential: api.Potential, ensemble: api.Ensemble,
     the ``GRID_INVALID`` sentinel through the same flag; the driver then
     re-derives the grid from the snapshot box instead of growing
     capacities. The ensemble and barostat state thread through both scan
-    levels in the carry.
+    levels in the carry. Each segment's output adds ``nbr_live``, the live
+    entries of its list (``nlist >= 0``), which the driver sums into
+    ``MDResult.nbr_live_slots``.
     """
     # (k + 0.5) * rcut floors back to exactly k cells (k * rcut can lose a
     # cell to float rounding — see _dyn_cell_list_fn)
@@ -416,13 +426,16 @@ def md_outer_engine(potential: api.Potential, ensemble: api.Ensemble,
 
     def outer_seg(carry: OuterCarry, seg_len: int, params, typ, masses, dt):
         nlist, ovf = nbr_fn(carry.pos, typ, carry.box)
+        with jax.named_scope(neighbors.SCOPE):
+            overflow = jnp.maximum(carry.overflow, ovf)
+            live = jnp.sum(nlist >= 0, dtype=jnp.int32)
         inner = MDCarry(carry.pos, carry.vel, carry.force, carry.ens,
                         carry.box, carry.baro)
         inner, th = scan_segment(md_step, inner, seg_len,
                                  params, nlist, typ, masses, dt)
-        return OuterCarry(inner.pos, inner.vel, inner.force,
-                          jnp.maximum(carry.overflow, ovf), inner.ens,
-                          inner.box, inner.baro), th
+        return OuterCarry(inner.pos, inner.vel, inner.force, overflow,
+                          inner.ens, inner.box, inner.baro), \
+            {**th, "nbr_live": live}
 
     return OuterEngine(outer_seg, donate=donate)
 
