@@ -113,3 +113,60 @@ def test_block_skipping_actually_skips():
     out = _fused(env_poison, s, coeffs, **kw)
     assert not bool(jnp.isnan(out).any())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
+
+
+def _grads(fn, env, s, coeffs, **kw):
+    def loss(env, s):
+        return jnp.sum(jnp.sin(fn(env, s, coeffs, **kw)))
+
+    return jax.grad(loss, argnums=(0, 1))(env, s)
+
+
+def _ref(env, s, coeffs):
+    return fused_ref.fused_env_tab_contract_ref(env, s, coeffs, LOWER, UPPER)
+
+
+def test_fused_copper_shape_ragged_matches_oracle():
+    """Copper's widths (K=32, M=128, 512 slots) with three atom tiles whose
+    ragged counts leave 1, 2 and 3 of the four neighbor tiles live: T and
+    both gradients. At this size the f32 oracle's own rounding of ds (sums
+    of ~400 terms) passes atol, so the oracle runs in float64 here. The env
+    cotangent is compared on the real slots; past a tile's last live slot
+    the kernel writes 0 (padding env rows are constants of the model, and
+    its mask drops their cotangent)."""
+    counts = [17, 100, 128, 3, 0, 64, 90, 1,            # 1 live tile
+              129, 255, 40, 200, 7, 131, 0, 250,        # 2
+              256, 384, 300, 12, 380, 260, 200, 399]    # 3
+    a, n = len(counts), 512
+    s, env, coeffs = _mk_inputs(jax.random.PRNGKey(5), a, n, 32, 128,
+                                jnp.float32, counts=counts)
+    out = _fused(env, s, coeffs)
+    genv_k, gs_k = _grads(_fused, env, s, coeffs)
+    with jax.enable_x64(True):
+        x64 = [jnp.asarray(np.asarray(x), jnp.float64)
+               for x in (env, s, coeffs)]
+        out_r = np.asarray(_ref(*x64))
+        genv_r, gs_r = (np.asarray(g) for g in _grads(_ref, *x64))
+    np.testing.assert_allclose(np.asarray(out), out_r, rtol=2e-5, atol=2e-5)
+    real = (np.arange(n)[None, :] < np.asarray(counts)[:, None])[..., None]
+    np.testing.assert_allclose(np.where(real, np.asarray(genv_k), 0.0),
+                               np.where(real, genv_r, 0.0),
+                               rtol=3e-4, atol=3e-5)
+    np.testing.assert_allclose(np.asarray(gs_k), gs_r, rtol=3e-4, atol=3e-5)
+
+
+def test_block_skipping_backward_writes_zeros():
+    """The backward skips the same tiles: with NaN env rows in the skipped
+    tile, ds and the env cotangent there are exactly 0, and the live tile's
+    are the unpoisoned run's."""
+    a, n = 8, 128
+    s, env, coeffs = _mk_inputs(jax.random.PRNGKey(6), a, n, 16, 32,
+                                jnp.float32, counts=[32] * a)
+    kw = dict(block_a=8, block_n=64)     # tiles: [0,64) live, [64,128) skipped
+    genv_ref, gs_ref = _grads(_fused, env, s, coeffs, **kw)
+    env_poison = env.at[:, 64:, :].set(jnp.nan)
+    genv, gs = _grads(_fused, env_poison, s, coeffs, **kw)
+    assert np.all(np.asarray(genv[:, 64:]) == 0.0)
+    assert np.all(np.asarray(gs[:, 64:]) == 0.0)
+    np.testing.assert_array_equal(np.asarray(genv), np.asarray(genv_ref))
+    np.testing.assert_array_equal(np.asarray(gs), np.asarray(gs_ref))

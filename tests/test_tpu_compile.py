@@ -65,16 +65,16 @@ def _fits_chip(compiled) -> int:
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_dp_fused_compiles_for_v5e(one_chip, sel, direction):
     """The fused kernel at the paper's widths (1024 atoms, K=32, M=128)
-    with the default (8, 128) tile, compiled for the TPU."""
-    ba, bn = fused_ops.DEFAULT_BLOCK_A, fused_ops.DEFAULT_BLOCK_N
+    with the wrapper's tile for the section, compiled for the TPU."""
     a, k, m = 1024, COPPER_DP.cheb_order, COPPER_DP.m_embed
+    ba, bn = fused_ops.tile(a, sel)
     n_pad = -(-sel // bn) * bn
-    args = [_shape(one_chip, (a, n_pad)), _shape(one_chip, (a, n_pad, 4)),
+    args = [_shape(one_chip, (a, n_pad)), _shape(one_chip, (4, a, n_pad)),
             _shape(one_chip, (k, m)),
             _shape(one_chip, (a // ba,), jnp.int32)]
     fn = dp_fused.fused_fwd
     if direction == "bwd":
-        args.append(_shape(one_chip, (a, 4, m)))
+        args.append(_shape(one_chip, (4, a, m)))
         fn = dp_fused.fused_bwd
     kw = dict(lower=COPPER_DP.table_lower, upper=COPPER_DP.table_upper,
               block_a=ba, block_n=bn, interpret=False)
