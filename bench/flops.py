@@ -45,26 +45,36 @@ def model_flops_per_step(model: Dict[str, Any], n_atoms: int,
 def dp_fused_cost_per_step(model: Dict[str, Any], n_atoms: int,
                            n_neighbors: int) -> Dict[str, float]:
     """Operations and HBM bytes of the ``dp_fused`` forward and backward
-    kernels in one force evaluation: per real neighbor the Chebyshev basis
-    of order K (a 3-operation recurrence per term; the backward adds its
-    derivative, 5 more), the table product B @ C (2*K*M; the backward does it
-    for the basis and its derivative), and the contraction with R~ (2*4*M;
-    the backward forms dR~ and the weighted sum for ds, 2*4*M each, and the
-    M-long dot with G', 2*M). Bytes are what the kernels must move: s and R~
-    in (4 + 16 B) each way, ds and dR~ out (20 B), T out and dT in (4*M
-    floats per atom and neighbor-type section), and each section's table
-    once per kernel."""
+    kernels in one force evaluation, as ``kernels/dp_fused/dp_fused.py``
+    computes them: in Chebyshev space, T = (R~^T B) C and its backward
+    through Q = dT C^T, so that G = B C is never formed.
+
+    Per real neighbor and Chebyshev term k < K: the forward's basis
+    recurrence T_k+1 = 2u T_k - T_k-1 (3) and its accumulation into
+    P = R~^T B (2*4); the backward's recurrence (3), its derivative's
+    T'_k+1 = 2 T_k + 2u T'_k - T'_k-1 (5), dR~ += Q_k T_k (2*4),
+    r_k = sum_c R~_c Q_ck (7) and ds += T'_k r_k (2). Per atom and per
+    type section: T = P C and Q = dT C^T, 2*4*K*M each. The per-neighbor
+    terms run over every section's real neighbors once, so they do not
+    grow with the sections; the per-atom terms do, as do T's and dT's
+    bytes and the tables. Left out: a few operations per neighbor outside
+    the loop over k (u from s, the domain mask) and the lane sums that
+    close P, whose length is the kernel's tile, not the model's. Bytes are
+    what the kernels must move: s and R~ in (4 + 16 B) each way, ds and
+    dR~ out (20 B), T out and dT in (4*M floats per atom and section), and
+    each section's table once per kernel."""
     k = int(model["cheb_order"])
     m = int(model["embed_widths"][-1])
-    ntypes = int(model["ntypes"])
-    per_nbr_fwd = 3 * k + 2 * k * m + 2 * 4 * m
-    per_nbr_bwd = 8 * k + 2 * (2 * k * m) + 2 * (2 * 4 * m) + 2 * m
-    flops = (per_nbr_fwd + per_nbr_bwd) * n_neighbors
+    sections = int(model["ntypes"])
+    per_nbr_fwd = (3 + 2 * 4) * k
+    per_nbr_bwd = (3 + 5 + 2 * 4 + 7 + 2) * k
+    per_atom = sections * 2 * (2 * 4 * k * m)
     f32 = 4
     nbr_bytes = (4 + 16) * 2 + (4 + 16)                    # fwd in, bwd in+out
-    atom_bytes = ntypes * 2 * 4 * m * f32        # T out, dT in, per section
-    table_bytes = 2 * ntypes * k * m * f32
-    return {"flops": float(flops),
+    atom_bytes = sections * 2 * 4 * m * f32      # T out, dT in, per section
+    table_bytes = 2 * sections * k * m * f32
+    return {"flops": float((per_nbr_fwd + per_nbr_bwd) * n_neighbors
+                           + per_atom * n_atoms),
             "bytes": float(nbr_bytes * n_neighbors + atom_bytes * n_atoms
                            + table_bytes)}
 

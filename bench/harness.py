@@ -36,6 +36,9 @@ from bench import flops, reference, systems, weights
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# The program's neighbor counters for the window's call (``MDResult``), which
+# ``scopes.layer_metrics`` reads; None where the program has no such field.
+NBR_COUNTERS = ("nbr_builds", "nbr_live_slots", "nbr_slots")
 
 
 class CompileCounter:
@@ -138,7 +141,7 @@ def prepare(config, cell, seed: int) -> Setup:
     from repro.md import api
     model = config["model"]
     pos, typ, box = systems.build_system(cell, seed)
-    masses = systems.masses(model["type_map"], typ)
+    masses = systems.masses(config["masses"], model["type_map"], typ)
     vel0 = systems.initial_velocities(seed, masses, cell["protocol"]["temp_k"])
     n_of_type = [int((typ == t).sum()) for t in range(int(model["ntypes"]))]
     ks = reference.neighbor_capacity(model, n_of_type, float(np.prod(box)))
@@ -351,6 +354,7 @@ def run(config, cell, seed: int, seconds: float, trace_dir: Optional[str],
         "host_syncs": int(res.host_syncs),
         "chunks": chunks,
     }
+    counters = {k: getattr(res, k, None) for k in NBR_COUNTERS}
     del res, sim
     t0 = time.perf_counter()
     checks = check(setup, out, cell["limits"]) if checked else None
@@ -364,5 +368,5 @@ def run(config, cell, seed: int, seconds: float, trace_dir: Optional[str],
             setup.model, n, setup.n_neighbors),
         "dp_fused_per_eval": flops.dp_fused_cost_per_step(
             setup.model, n, setup.n_neighbors),
-        "checks": checks, "setup": setup, "out": out,
+        "counters": counters, "checks": checks, "setup": setup, "out": out,
     }

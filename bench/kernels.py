@@ -3,8 +3,10 @@
 import re
 
 # The dp_fused pallas_calls pass no name=; on the chip their custom calls
-# are named after the jitted wrappers around them, under autodiff:
-# jvp_jit_fused_fwd__.8 and transpose_jvp_jit_fused_bwd___.26.
+# are named after the kernels, fused_fwd.N and fused_bwd.N. Traces of the
+# kernels that formed G (tests/bench/fixtures/trace_v5e_cu16k.json) name
+# them after their jitted wrappers under autodiff, jvp_jit_fused_fwd__.8
+# and transpose_jvp_jit_fused_bwd___.26; the pattern takes both.
 DP_FUSED = re.compile(r"(^|_)fused_(fwd|bwd)(_|\.|$)")
 
 

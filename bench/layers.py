@@ -49,7 +49,7 @@ def parse_args(argv):
 
 def summarize(tr: Dict[str, Any], counters: Dict[str, Optional[int]],
               steps: int) -> Dict[str, Any]:
-    """The report of a scoped trace ``tr`` (``scopes.load``) of a window of
+    """The report of a scoped trace ``tr`` (``trace.load``) of a window of
     ``steps`` MD steps and the program's ``counters`` for that call."""
     from bench import scopes, trace
     ms = lambda ns: ns * 1e-6 / steps            # noqa: E731
@@ -108,7 +108,7 @@ def main(argv=None) -> int:
                 with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
                     res = call()
         path = trace.find_xplane(trace_dir)
-        tr = scopes.load(path)
+        tr = trace.load(path)
         if args.xplane:
             with open(path, "rb") as f, gzip.open(args.xplane, "wb") as g:
                 shutil.copyfileobj(f, g)

@@ -4,9 +4,10 @@
     python3 bench/run.py --workload cu16k_nve --seed 7 --seconds 10 --trace 0
 
 The workload names a cell of ``BENCHMARK.json``; its configuration file
-(``bench/configs/``), its cell file (``bench/cells/``) and the readers of its
-metrics (``bench/metrics/<metric>.py``) are found by name, so a new cell or
-metric is new files and entries, not an edit here. With ``--trace 0`` the
+(``bench/configs/``), its cell file (``bench/cells/``), its lattice
+(``bench/lattices/<lattice>.py``) and the readers of its metrics
+(``bench/metrics/<metric>.py``) are found by name, so a new cell or metric
+is new files and entries, not an edit here. With ``--trace 0`` the
 line carries the cell's end-to-end metrics, with ``--trace 1`` its per-layer
 metrics, read from a profiler trace of the window. The last lines on
 standard error, and the ``checks`` key that ends the result line, give each
@@ -22,7 +23,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse
-import importlib.util
 import json
 import os
 import shutil
@@ -44,14 +44,6 @@ def parse_args(argv):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     return ap.parse_args(argv)
-
-
-def load_reader(name: str):
-    path = os.path.join(ROOT, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
 
 
 def metric_entries(bench, workload: str, trace: bool):
@@ -107,7 +99,7 @@ def main(argv=None) -> int:
 
     metrics = {}
     for m in metric_entries(bench, args.workload, bool(args.trace)):
-        value = load_reader(m["name"])(record)
+        value = systems.by_name("metrics", m["name"]).read(record)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     info["memory_peak_bytes"] = max(record["peak_bytes_per_device"])

@@ -14,10 +14,10 @@ a v5e trace of ``cu16k_nve``). The program's host phases are
 ``jax.profiler.TraceAnnotation`` spans (``md.run``, ``md.first_build``,
 ``md.chunk``, ...), which ``trace.load`` keeps with the other host spans.
 
-``load`` is ``trace.load`` with one more key, ``scopes``: for each device,
-the ``op_name`` of each operation event, in the order of ``devices``, ``""``
-where the event has none. ``layer_ns`` reduces it by the attribution rule
-of ``attribute``.
+``trace.load`` keeps, under ``scopes``, each device operation event's
+``op_name`` as ``op_scopes`` reads it; ``layer_ns`` reduces them by the
+attribution rule of ``attribute``, and ``layer_metrics`` to the per-layer
+metrics.
 """
 
 from __future__ import annotations
@@ -30,23 +30,6 @@ from bench import trace
 SCOPE_STAT = "tf_op"
 UNSCOPED = "unscoped"
 SCOPE = re.compile(r"(?<![\w.])(?:md|dp)\.[A-Za-z_]+")
-
-
-def load(path: str) -> Dict[str, Any]:
-    """``trace.load(path)`` and, under ``scopes``, each device operation
-    event's ``op_name`` (same planes, lines and order as ``devices``)."""
-    out = trace.load(path)
-    with open(path, "rb") as f:
-        raw = op_scopes(f.read())
-    scopes: Dict[str, List[str]] = {}
-    for dev, evs in out["devices"].items():
-        names, ops = raw.get(dev, ([], []))
-        if [trace.op_name(n) for n in names] != [e[0] for e in evs]:
-            raise ValueError(f"device {dev}: the raw read of {path} does "
-                             f"not match its operation events")
-        scopes[dev] = ops
-    out["scopes"] = scopes
-    return out
 
 
 # The XSpace protobuf (tsl/profiler/protobuf/xplane.proto), as far as

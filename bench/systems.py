@@ -3,25 +3,26 @@
 A configuration (``bench/configs/<name>.json``) holds the model's published
 sizes under ``model`` and the seeded-weight recipe under ``weights``; a cell
 (``bench/cells/<name>.json``) holds the system and the MD protocol. This
-module builds the atoms (a jittered FCC crystal), the initial velocities
-and the seed's integer streams. The lattice and the velocity draw are
-copies of the program's ``md/lattice.py`` and ``md/integrator.py``, so that
-the benchmark's inputs do not move when those change.
+module builds the atoms (the cell's lattice, ``bench/lattices/<name>.py``,
+found by name, and a seeded jitter), each atom's mass (from the
+configuration's ``masses``), the initial velocities and the seed's integer
+streams. The velocity draw is a copy of the program's ``md/integrator.py``,
+so that the benchmark's inputs do not move when that changes.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
+import re
 from typing import Any, Dict, Tuple
 
 import numpy as np
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# atomic masses (amu)
-MASS = {"Cu": 63.546}
-FCC_CU_A = 3.634          # paper Sec. 4
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]*")
 KB_EV = 8.617333262e-5    # eV / K
 FORCE_TO_ACC = 9.64853329045e-3          # (eV/A)/amu in A/fs^2
 
@@ -66,31 +67,36 @@ def sim_seed(seed: int) -> int:
     return (lo ^ (hi * 0x9E3779B1)) & 0x7FFFFFFF
 
 
-def fcc(n: Tuple[int, int, int], a: float = FCC_CU_A):
-    """FCC lattice: (positions (N, 3), types (N,), box (3,))."""
-    base = np.array([[0.0, 0.0, 0.0], [0.5, 0.5, 0.0], [0.5, 0.0, 0.5],
-                     [0.0, 0.5, 0.5]])
-    grid = np.stack(np.meshgrid(*[np.arange(k) for k in n], indexing="ij"),
-                    axis=-1).reshape(-1, 1, 3)
-    pos = (grid + base[None]).reshape(-1, 3) * a
-    return pos, np.zeros(len(pos), np.int32), np.asarray(n, float) * a
+def by_name(kind: str, name: str):
+    """The module ``bench/<kind>/<name>.py`` (a lattice builder, a metric
+    reader) of the name a cell file or ``BENCHMARK.json`` gives."""
+    path = os.path.join(ROOT, "bench", kind, f"{name}.py")
+    if not NAME.fullmatch(name) or not os.path.isfile(path):
+        raise ValueError(f"no {kind} named {name!r} under bench/{kind}/")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def build_system(cell: Dict[str, Any], seed: int):
     """(pos float32 (N, 3) inside the box, typ int32 (N,), box float64 (3,))
-    of the cell's system for ``seed``. Every seed gives the same atoms,
-    types and box; only the jitter moves."""
+    of the cell's system for ``seed``: the lattice ``system["lattice"]``
+    (``build(system, rng)`` of ``bench/lattices/<lattice>.py``) with a
+    Gaussian jitter of ``system["jitter_a"]`` on every coordinate, both
+    drawn from the seed. Every seed gives the same atoms, types and box."""
     system = cell["system"]
     rng = np.random.default_rng(list(seed_words(seed)))
-    if system["lattice"] != "fcc":
-        raise ValueError(f"unknown lattice {system['lattice']!r}")
-    pos, typ, box = fcc(tuple(system["cells"]))
+    pos, typ, box = by_name("lattices", system["lattice"]).build(system, rng)
     pos = pos + rng.normal(0.0, system["jitter_a"], pos.shape)
     return np.mod(pos, box).astype(np.float32), typ.astype(np.int32), box
 
 
-def masses(type_map, typ: np.ndarray) -> np.ndarray:
-    return np.array([MASS[t] for t in type_map])[typ]
+def masses(table: Dict[str, float], type_map, typ: np.ndarray) -> np.ndarray:
+    """Each atom's mass (amu): the configuration's ``masses`` ``table``
+    by element, through its ``type_map``."""
+    return np.array([table[t] for t in type_map])[typ]
 
 
 def initial_velocities(seed: int, masses: np.ndarray, temp_k: float

@@ -1,7 +1,8 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
 ``load`` turns the ``.xplane.pb`` that ``jax.profiler.trace`` writes into a
-small dict: for each device plane the operation events, and for the host
+small dict: for each device plane the operation events and their
+``op_name``s (the program's scopes, ``bench/scopes.py``), and for the host
 the spans that last at least ``HOST_MIN_NS``. The reductions below work on
 that dict, so they are tested on a recorded one (``tests/bench``) and read
 the chip's trace the same way in every run.
@@ -40,11 +41,15 @@ def find_xplane(trace_dir: str) -> str:
 
 
 def load(path: str) -> Dict[str, Any]:
-    """{"devices": {id: [event, ...]}, "host": [event, ...]} from one
-    ``.xplane.pb``. Each event is ``[name, start_ns, duration_ns]``."""
+    """{"devices": {id: [event, ...]}, "host": [event, ...], "scopes": {id:
+    [op_name, ...]}} from one ``.xplane.pb``. Each event is ``[name,
+    start_ns, duration_ns]``; ``scopes`` holds each device operation
+    event's ``op_name`` (``scopes.op_scopes``), in the order of
+    ``devices``, ``""`` where the event has none."""
     from jax.profiler import ProfileData
+    from bench.scopes import op_scopes
     data = ProfileData.from_file(path)
-    out: Dict[str, Any] = {"devices": {}, "host": []}
+    out: Dict[str, Any] = {"devices": {}, "host": [], "scopes": {}}
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
@@ -62,6 +67,14 @@ def load(path: str) -> Dict[str, Any]:
                     if ev.duration_ns >= HOST_MIN_NS:
                         out["host"].append([ev.name, float(ev.start_ns),
                                             float(ev.duration_ns)])
+    with open(path, "rb") as f:
+        raw = op_scopes(f.read())
+    for dev, evs in out["devices"].items():
+        names, ops = raw.get(dev, ([], []))
+        if [op_name(n) for n in names] != [e[0] for e in evs]:
+            raise ValueError(f"device {dev}: the raw read of {path} does "
+                             f"not match its operation events")
+        out["scopes"][dev] = ops
     return out
 
 

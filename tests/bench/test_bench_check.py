@@ -28,6 +28,8 @@ CONFIG = {"model": {"ntypes": 1, "type_map": ["Cu"], "rcut": 4.0,
                     "axis_neuron": 4, "type_one_side": True,
                     "fit_widths": [24, 24, 24], "table_lower": -2.0,
                     "table_upper": 10.0, "cheb_order": 32, "dtype": "float32"},
+          "masses": systems.load_json(ROOT, "bench", "configs",
+                                      "copper.json")["masses"],
           "precision": "highest", "weights": {"head_scale": 1.0}}
 COPPER = systems.load_json(ROOT, "bench", "cells", "cu16k_nve.json")
 LIMITS = COPPER["limits"]
@@ -74,7 +76,8 @@ def test_control_fails(sound):
     assert not harness.passed(checks), checks
 
 
-def test_state_returned_unchanged_fails(monkeypatch):
+def plant_unchanged_state(monkeypatch):
+    """Break the program's MD step so that it returns its state unchanged."""
     from repro.md import stepper
     real = stepper.make_md_step
 
@@ -87,11 +90,10 @@ def test_state_returned_unchanged_fails(monkeypatch):
         return md_step
 
     monkeypatch.setattr(stepper, "make_md_step", broken)
-    checks = run_toy()["checks"]
-    assert not harness.passed(checks), checks
 
 
-def test_kick_with_the_wrong_sign_fails(monkeypatch):
+def plant_backwards_kick(monkeypatch):
+    """Break the program's half kick so that it pushes against the force."""
     from repro.md import integrator
     real = integrator.verlet_half_kick
 
@@ -99,6 +101,16 @@ def test_kick_with_the_wrong_sign_fails(monkeypatch):
         return real(vel, -force, masses, dt)
 
     monkeypatch.setattr(integrator, "verlet_half_kick", backwards)
+
+
+def test_state_returned_unchanged_fails(monkeypatch):
+    plant_unchanged_state(monkeypatch)
+    checks = run_toy()["checks"]
+    assert not harness.passed(checks), checks
+
+
+def test_kick_with_the_wrong_sign_fails(monkeypatch):
+    plant_backwards_kick(monkeypatch)
     checks = run_toy()["checks"]
     assert not harness.passed(checks), checks
 

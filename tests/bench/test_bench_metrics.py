@@ -29,7 +29,7 @@ def record(trace=None):
             "model_flops_per_eval": 1e9,
             "dp_fused_per_eval": {"flops": 4e9, "bytes": 1e7},
             "peaks": {"flops_per_s": 2e12, "bytes_per_s": 1e11},
-            "trace": trace}
+            "counters": {}, "trace": trace}
 
 
 def synthetic_trace():
@@ -59,7 +59,11 @@ def test_trace_readers():
 
 
 @pytest.mark.parametrize("name", ["device_idle_share", "dp_fused_roofline",
-                                  "dp_fused_ms_per_step"])
+                                  "dp_fused_ms_per_step",
+                                  "neighbors_ms_per_step", "env_ms_per_step",
+                                  "scatter_ms_per_step",
+                                  "force_backward_ms_per_step",
+                                  "nbr_slot_fill"])
 def test_nothing_to_read_gives_nothing(name):
     assert reader(name)(record(None)) is None
     empty = {"devices": {"0": []}, "host": [["bench.window", 0.0, 10.0]]}

@@ -21,6 +21,7 @@ WATER = {"ntypes": 2, "type_map": ["O", "H"], "rcut": 4.0, "rcut_smth": 0.5,
          "sel": [16, 32], "embed_widths": [8, 16, 32], "axis_neuron": 4,
          "fit_widths": [24, 24, 24]}
 FCC = {"lattice": "fcc", "cells": [3, 3, 3], "jitter_a": 0.1}
+CU_MASS = systems.load_json(ROOT, "bench", "configs", "copper.json")["masses"]
 SYSTEMS = {"copper": COPPER, "water": WATER}
 
 
@@ -95,7 +96,7 @@ def test_initial_velocities_are_the_programs_draw():
     from repro.md import integrator, lattice
     pos, typ, _ = systems.build_system({"system": FCC}, 5)
     seed = 2**40 + 77
-    masses = systems.masses(["Cu"], typ)
+    masses = systems.masses(CU_MASS, ["Cu"], typ)
     mine = systems.initial_velocities(seed, masses, 330.0)
     theirs = integrator.init_velocities(
         jax.random.PRNGKey(systems.sim_seed(seed)),
@@ -106,7 +107,7 @@ def test_initial_velocities_are_the_programs_draw():
 
 def test_integration_conserves_energy_across_list_rebuilds():
     model, pos, typ, box, _, _, params = frame("copper")
-    masses = systems.masses(["Cu"], typ)
+    masses = systems.masses(CU_MASS, ["Cu"], typ)
     vel = systems.initial_velocities(3, masses, 3000.0)
     # a 0.2 A skin makes the hot atoms outrun the list within the run
     run = reference.integrate(params, model, pos, vel, typ, box, masses,

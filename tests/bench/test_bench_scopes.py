@@ -167,6 +167,30 @@ def test_recorded_v5e_scoped_trace():
     assert got[scopes.UNSCOPED] < 0.05 * sum(got.values())
 
 
+LINE_READERS = ["neighbors_ms_per_step", "env_ms_per_step",
+                "scatter_ms_per_step", "force_backward_ms_per_step",
+                "nbr_slot_fill"]
+
+
+@pytest.mark.parametrize("name", LINE_READERS)
+def test_line_readers_on_the_recorded_v5e_scoped_trace(name):
+    """Each per-layer metric of the result line reads what
+    ``scopes.layer_metrics`` gives, from the run's record."""
+    tr = load("trace_v5e_cu16k_scoped.json")
+    counters = {"nbr_builds": 1, "nbr_live_slots": 684, "nbr_slots": 1000}
+    rec = {"trace": tr, "steps": 2, "counters": counters}
+    want = scopes.layer_metrics(tr, 2, counters)[name]
+    assert want is not None and want > 0
+    assert reader(name)(rec) == want
+
+
+def test_scopes_cover_the_recorded_v5e_trace():
+    """The program's scopes leave under 1% of the busy time unnamed, so
+    the line can go without ``device_unscoped_share``."""
+    tr = load("trace_v5e_cu16k_scoped.json")
+    assert scopes.layer_metrics(tr, 2, {})["device_unscoped_share"] < 1.0
+
+
 def _xspace(ops_line: str) -> bytes:
     """A serialized XSpace with one TPU plane (``ops_line`` is its
     ``XLA Ops`` line in protobuf text format) and the benchmark's window
@@ -199,9 +223,9 @@ planes {{
 
 
 def test_load_reads_op_names_from_event_metadata(tmp_path):
-    """``scopes.load`` on a serialized trace: ``devices`` and ``host`` as
-    ``trace.load`` gives them, and each operation event's ``op_name`` in
-    the same order, ``""`` where the metadata has none."""
+    """``trace.load`` on a serialized trace: ``devices`` and ``host``, and
+    under ``scopes`` each operation event's ``op_name`` in the same order,
+    ``""`` where the metadata has none."""
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_xspace("""
       events { metadata_id: 7 offset_ps: 0 duration_ps: 5000 }
@@ -209,8 +233,8 @@ def test_load_reads_op_names_from_event_metadata(tmp_path):
       events { metadata_id: 10 offset_ps: 8000 duration_ps: 1000 }
       events { metadata_id: 7 offset_ps: 9000 duration_ps: 1000
                stats { metadata_id: 2 int64_value: 3 } }"""))
-    tr = scopes.load(str(path))
-    assert tr["devices"] == trace.load(str(path))["devices"] == {"0": [
+    tr = trace.load(str(path))
+    assert tr["devices"] == {"0": [
         ["fusion.1", 1000.0, 5.0], ["copy.2", 1006.0, 2.0],
         ["copy.3", 1008.0, 1.0], ["fusion.1", 1009.0, 1.0]]}
     assert tr["host"] == [["bench.window", 0.0, 200000.0]]
